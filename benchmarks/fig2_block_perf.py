@@ -20,6 +20,7 @@ from repro.core.pruner import oneshot_prune
 from repro.core.sparsity import SparsityConfig
 from repro.kernels import pack_bsr
 from repro.models import init_model
+from repro.runtime.compile_cache import use_compile_cache
 
 from benchmarks.table1_block_sweep import BLOCK_SHAPES, SPARSITY, _TARGETS
 
@@ -45,4 +46,5 @@ def run(emit=print):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run()

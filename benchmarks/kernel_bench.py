@@ -44,6 +44,7 @@ from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ops import bsr_linear
 from repro.models.attention import decode_attention
 from repro.runtime.bench_io import update_bench_json
+from repro.runtime.compile_cache import use_compile_cache
 
 SHAPES = [("proj_768", 768, 768), ("ffn_3072", 3072, 768)]
 DENSITIES = (1.0, 0.5, 0.2, 0.1, 0.05)
@@ -271,6 +272,7 @@ def run_flash_decode(emit=print, smoke=False, write_json=True, reps=7):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     smoke = "--smoke" in sys.argv
     write_json = "--no-json" not in sys.argv
     run(smoke=smoke, write_json=write_json)

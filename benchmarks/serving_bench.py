@@ -64,6 +64,7 @@ import numpy as np
 from repro.configs.base import LayerKind, ModelConfig
 from repro.models import init_model
 from repro.runtime.bench_io import repo_root, update_bench_json
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import ServingSpec, prepare_servable
 
 SLOT_COUNTS = (1, 4, 16)
@@ -931,4 +932,5 @@ def main(argv):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main(sys.argv[1:])

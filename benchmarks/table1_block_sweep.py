@@ -31,6 +31,7 @@ from repro.core.sparsity import SparsityConfig
 from repro.core.pruner import oneshot_prune
 from repro.models import bert as bert_mod
 from repro.models import init_model
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import ServingSpec, prepare_servable
 
 SEQ, BATCH, SPARSITY = 384, 1, 0.8
@@ -106,4 +107,5 @@ def run(reps=3, emit=lambda s: print(s, flush=True)):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run()

@@ -25,6 +25,7 @@ from repro.launch.steps import cross_entropy
 from repro.models import bert as bert_mod
 from repro.models import init_model
 from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state
+from repro.runtime.compile_cache import use_compile_cache
 
 MASK_ID = 3
 _TARGETS = ("attn/wq", "attn/wk", "attn/wv", "attn/wo", "ffn/wi", "ffn/wo")
@@ -117,4 +118,5 @@ def run(pretrain_steps=150, finetune_steps=60, emit=print):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run()

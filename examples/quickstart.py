@@ -18,10 +18,12 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.pruner import sparsity_report, tied_prune
 from repro.models import init_model, model_forward
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import ServingSpec, load_servable, prepare_servable
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("bert_base", smoke=True)
     params = init_model(jax.random.PRNGKey(0), cfg)
     toks = jnp.asarray(np.random.RandomState(0).randint(

@@ -32,6 +32,7 @@ from repro.configs.registry import get_config
 from repro.core.pruner import oneshot_prune, tied_prune
 from repro.models import init_model, model_forward
 from repro.runtime.bench_io import update_bench_json
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import ServingSpec, load_servable, prepare_servable
 
 SEQ, BATCH = 384, 1
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--save", default=None, metavar="DIR",
                     help="persist the servable and re-serve via load_servable")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("initializing BERT_BASE (110M)...")
     cfg = get_config("bert_base")

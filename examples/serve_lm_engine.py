@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.configs.registry import get_config
 from repro.models import init_model
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import ServingSpec, prepare_servable
 
 
@@ -87,6 +88,7 @@ def main():
                          "scales, dequant fused into the plan matmul "
                          "(docs/API.md §Quantized sparse packs)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=True)
     print(f"initializing {cfg.arch} ({cfg.family})...")

@@ -11,14 +11,14 @@ import dataclasses
 import logging
 import tempfile
 
-import jax
-
 from repro.configs.registry import get_config
 from repro.core.pruner import sparsity_report
 from repro.core.sparsity import SparsityConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, Trainer
 from repro.optim.adamw import AdamWConfig
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import ServingSpec, prepare_servable
 from repro.runtime.fault_tolerance import FaultInjector, FaultToleranceConfig
 
@@ -31,12 +31,13 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--arch", default="deepseek_7b")
     args = ap.parse_args()
+    use_compile_cache()
 
     sp = SparsityConfig(block_shape=(16, 16), sparsity=0.7,
                         lambda_reg=1e-4, start_step=10,
                         end_step=max(args.steps - 10, 11))
     cfg = dataclasses.replace(get_config(args.arch, smoke=True), sparsity=sp)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ckpt = tempfile.mkdtemp(prefix="repro_lm_")
 
     tcfg = TrainConfig(

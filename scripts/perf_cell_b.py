@@ -31,7 +31,8 @@ for label, mode in (("before", "train"), ("after", "inference")):
     n_exp = ha.count_expert_params(specs["params"])
     mf = ha.model_flops_estimate(cfg, SHAPES["prefill_32k"], n_params, n_exp,
                                  "prefill")
-    roof = ha.Roofline(la["flops"], la["bytes"], la["coll"]["total"], 256, mf)
+    roof = ha.Roofline(la["flops"], la["bytes"], la["coll"]["total"], 256, mf,
+                       device_kind="TPU v5 lite")
     mem = compiled.memory_analysis()
     out[label] = {**roof.to_dict(),
                   "arg_bytes": getattr(mem, "argument_size_in_bytes", 0),

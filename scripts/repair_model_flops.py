@@ -29,7 +29,8 @@ for path in sorted(glob.glob(sys.argv[1] if len(sys.argv) > 1
     mf = ha.model_flops_estimate(cfg, shape, n_params, n_expert, shape.kind)
     r = cell["roofline"]
     roof = ha.Roofline(r["flops_per_dev"], r["hbm_bytes_per_dev"],
-                       r["coll_bytes_per_dev"], r["n_devices"], mf)
+                       r["coll_bytes_per_dev"], r["n_devices"], mf,
+                       device_kind=r.get("device_kind", "TPU v5 lite"))
     cell["n_params"], cell["n_expert_params"] = n_params, n_expert
     cell["roofline"] = roof.to_dict()
     json.dump(cell, open(path, "w"), indent=1)

@@ -75,8 +75,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import exec_plan as xp
-from repro.kernels.bsr_matmul import KernelBSR, masked_matmul
-from repro.kernels.flash_decode import default_kv_split
+from repro.kernels.bsr_matmul import (KernelBSR, masked_matmul,
+                                      pallas_interpret_default,
+                                      pallas_tile_supported)
+from repro.kernels.flash_decode import default_kv_split, flash_decode_aligned
 
 CANDIDATES = ("dense", "gather", "rowpack", "plan", "pallas", "masked",
               "plan_pallas")
@@ -356,9 +358,8 @@ def _candidate_fn(pack: KernelBSR, name: str):
         w = jnp.asarray(dense_from_pack(pack))
         mask = jnp.asarray(mp.tile_mask)
         tile = pack.tile
-        return (jax.jit(lambda x, w_: masked_matmul(
-            x, w_, mask, tile=tile,
-            interpret=jax.default_backend() != "tpu")), w)
+        return (jax.jit(lambda x, w_: masked_matmul(x, w_, mask, tile=tile)),
+                w)
     if name == "plan_pallas":
         plan = xp.plan_for_pack(pack)
         data = xp.pack_plan_data(plan, pack.data)
@@ -531,7 +532,11 @@ def choose_backend(pack: KernelBSR, m: int = 256, *,
         candidates = list(CANDIDATES)
         if quant != "none":
             candidates += list(QUANT_CANDIDATES)
-        if not stub and timer is None and jax.default_backend() != "tpu":
+        if (not stub and timer is None and jax.default_backend() != "tpu"
+                or not pallas_interpret_default()
+                and not pallas_tile_supported(pack.tile)):
+            # off TPU the Pallas arms only interpret; on TPU they cannot
+            # compile tiles off the (8, 128) tiling
             candidates = [c for c in candidates if c not in INTERPRET_ONLY]
     mode = "stub" if stub else "wallclock"
     # the candidate set is part of the key: a winner measured over a
@@ -663,7 +668,9 @@ def choose_decode_kernel(b: int = 8, t: int = 512, hq: int = 8,
     cache = cache if cache is not None else default_cache()
     split = int(kv_split) if kv_split else default_kv_split(t)
     candidates = list(DECODE_CANDIDATES)
-    if not stub and timer is None and jax.default_backend() != "tpu":
+    if (not stub and timer is None and jax.default_backend() != "tpu"
+            or not pallas_interpret_default()
+            and not flash_decode_aligned(hkv, d)):
         candidates = [c for c in candidates
                       if c not in DECODE_INTERPRET_ONLY]
     mode = "stub" if stub else "wallclock"

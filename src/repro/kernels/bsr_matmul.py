@@ -35,6 +35,39 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bsr import BSR, bsr_to_dense
 
 
+def pallas_interpret_default(interpret: bool | None = None) -> bool:
+    """Resolve a Pallas entry's ``interpret`` flag; ``None`` (every entry's
+    default) lets the platform decide: kernels compile on TPU, everywhere
+    else interpret mode is the correctness oracle (docs/PERF.md: orders of
+    magnitude slower)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def pallas_tile_supported(tile: Tuple[int, int]) -> bool:
+    """Whether a compiled (non-interpret) kernel can take ``(bn, bk)`` tiles:
+    every block it streams -- x (bm, bk), weights (bn, bk), output and bias
+    (bm, bn) -- must obey the TPU (8, 128) tiling, so both tile dims must be
+    lane multiples."""
+    bn, bk = tile
+    return bn % 128 == 0 and bk % 128 == 0
+
+
+def check_pallas_tile(tile: Tuple[int, int],
+                      interpret: bool | None = None) -> None:
+    """Raise a ValueError for a tile the compiled kernels cannot take (the
+    Mosaic lowering would otherwise refuse it with a block-shape error deep
+    inside the first call). Interpret mode takes any tile."""
+    if (not pallas_interpret_default(interpret)
+            and not pallas_tile_supported(tile)):
+        raise ValueError(
+            f"tile {tuple(tile)} cannot run through a compiled Pallas "
+            f"backend on {jax.default_backend()}: both tile dims must be "
+            f"multiples of 128; use the XLA 'plan' backend or a 128-aligned "
+            f"tile")
+
+
 # --------------------------------------------------------------------------
 # Host-side packing
 # --------------------------------------------------------------------------
@@ -180,7 +213,7 @@ def _dds_call(x, data, row_id, col_id, *, pack_static, bm, interpret):
 
 
 def dds(x: jax.Array, w: KernelBSR, *, bm: int = 128,
-        interpret: bool = True) -> jax.Array:
+        interpret: bool | None = None) -> jax.Array:
     """Y(M, N) = X(M, K) @ W^T with tile skipping. Pads M to bm internally."""
     m, k = x.shape
     assert k == w.shape[1], (x.shape, w.shape)
@@ -189,12 +222,13 @@ def dds(x: jax.Array, w: KernelBSR, *, bm: int = 128,
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     y = _dds_call(x, w.data, jnp.asarray(w.row_id), jnp.asarray(w.col_id),
-                  pack_static=(w.shape, w.tile), bm=bm, interpret=interpret)
+                  pack_static=(w.shape, w.tile), bm=bm,
+                  interpret=pallas_interpret_default(interpret))
     return y[:m] if pad else y
 
 
 def dds_t(dy: jax.Array, w: KernelBSR, *, bm: int = 128,
-          interpret: bool = True) -> jax.Array:
+          interpret: bool | None = None) -> jax.Array:
     """dX(M, K) = dY(M, N) @ W, reusing the DDS kernel on the transposed
     pattern (tiles re-sorted by column on host at pack time)."""
     t_data = jnp.transpose(w.data[jnp.asarray(w.t_perm)], (0, 2, 1))
@@ -207,7 +241,7 @@ def dds_t(dy: jax.Array, w: KernelBSR, *, bm: int = 128,
                   jnp.asarray(w.t_col_id()),
                   pack_static=((w.shape[1], w.shape[0]),
                                (w.tile[1], w.tile[0])),
-                  bm=bm, interpret=interpret)
+                  bm=bm, interpret=pallas_interpret_default(interpret))
     return x[:m] if pad else x
 
 
@@ -256,7 +290,7 @@ def _sddmm_call(dy, x, row_id, col_id, *, pack_static, bm, interpret):
 
 
 def sddmm(dy: jax.Array, x: jax.Array, w: KernelBSR, *, bm: int = 128,
-          interpret: bool = True) -> jax.Array:
+          interpret: bool | None = None) -> jax.Array:
     """Gradient w.r.t. stored tiles. Returns (nnzt, bn, bk); padding tiles
     receive garbage and are zeroed here (they must stay dead)."""
     m = x.shape[0]
@@ -267,7 +301,7 @@ def sddmm(dy: jax.Array, x: jax.Array, w: KernelBSR, *, bm: int = 128,
         x = jnp.pad(x, ((0, pad), (0, 0)))
     g = _sddmm_call(dy, x, jnp.asarray(w.row_id), jnp.asarray(w.col_id),
                     pack_static=(w.shape, w.tile, w.data.dtype),
-                    bm=bm, interpret=interpret)
+                    bm=bm, interpret=pallas_interpret_default(interpret))
     return g * jnp.asarray(w.pad_mask())[:, None, None].astype(g.dtype)
 
 
@@ -319,7 +353,7 @@ def _masked_call(x, w, tile_mask, *, tile, bm, interpret):
 
 def masked_matmul(x: jax.Array, w_dense: jax.Array, tile_mask: jax.Array,
                   *, tile: Tuple[int, int] = (128, 128), bm: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """Y = X @ W^T skipping MXU work on zero tiles; W stays dense in HBM.
 
     Saves compute but NOT memory traffic -- quantifying why format support
@@ -331,7 +365,7 @@ def masked_matmul(x: jax.Array, w_dense: jax.Array, tile_mask: jax.Array,
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     y = _masked_call(x, w_dense, tile_mask, tile=tile, bm=bm,
-                     interpret=interpret)
+                     interpret=pallas_interpret_default(interpret))
     return y[:m] if pad else y
 
 
@@ -422,7 +456,7 @@ def _plan_dds_call(x, data_rp, b, row_seq, col_seq, vrow_seq, slot_seq, *,
 def plan_dds(x: jax.Array, data_rp: jax.Array, schedule, *, n: int,
              tile: Tuple[int, int], bias: jax.Array | None = None,
              act: str | None = None, bm: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """Y(M, N) = X(M, K) @ W^T from the row-grouped (V, P, bn, bk) pack.
 
     ``schedule`` is the (row_seq, col_seq, vrow_seq, slot_seq) tuple from
@@ -430,8 +464,8 @@ def plan_dds(x: jax.Array, data_rp: jax.Array, schedule, *, n: int,
     row, row_seq sentinel-terminated. ``bias`` (N,) and ``act`` fuse into
     the row-change write.
     """
+    check_pallas_tile(tile, interpret)
     m = x.shape[0]
-    bn, bk = tile
     row_seq, col_seq, vrow_seq, slot_seq = schedule
     bm = min(bm, _ceil_mult(m, 8))
     pad = (-m) % bm
@@ -443,17 +477,18 @@ def plan_dds(x: jax.Array, data_rp: jax.Array, schedule, *, n: int,
     y = _plan_dds_call(x, data_rp, b, jnp.asarray(row_seq),
                        jnp.asarray(col_seq), jnp.asarray(vrow_seq),
                        jnp.asarray(slot_seq), n=n, tile=tile, bm=bm,
-                       act=act, bias=has_bias, interpret=interpret)
+                       act=act, bias=has_bias,
+                       interpret=pallas_interpret_default(interpret))
     return y[:m] if pad else y
 
 
-def _plan_dds_q_kernel(row_ref, col_ref, vrow_ref, slot_ref, x_ref, w_ref,
-                       s_ref, b_ref, o_ref, acc_ref, *, act, bias):
+def _plan_dds_q_kernel(row_ref, col_ref, vrow_ref, slot_ref, s_ref, x_ref,
+                       w_ref, b_ref, o_ref, acc_ref, *, act, bias):
     # same schedule/accumulator protocol as _plan_dds_kernel; the block
-    # values arrive int8/fp8 and the per-block (or per-row-group) scale
-    # rides the scalar-prefetched schedule -- dequant is one scalar
-    # multiply on the tile's contribution, inside the accumulation, so
-    # fp32 weight values never exist outside VMEM.
+    # values arrive int8/fp8 and the per-step scale rides the scalar-
+    # prefetched schedule (SMEM) -- dequant is one scalar multiply on the
+    # tile's contribution, inside the accumulation, so fp32 weight values
+    # never exist outside VMEM.
     j = pl.program_id(1)
     first = (j == 0) | (row_ref[j] != row_ref[jnp.maximum(j - 1, 0)])
 
@@ -461,7 +496,7 @@ def _plan_dds_q_kernel(row_ref, col_ref, vrow_ref, slot_ref, x_ref, w_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += s_ref[0, 0] * jax.lax.dot_general(
+    acc_ref[...] += s_ref[j] * jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), w_ref[0, 0].astype(jnp.float32),
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -483,44 +518,39 @@ def _plan_dds_q_call(x, qvalues, scales, b, row_seq, col_seq, vrow_seq,
     nnzt = int(col_seq.shape[0])
     m = x.shape[0]
     grid = (m // bm, nnzt)
-    # 'block' scales are (V, P): one per schedule step at (vr[j], sl[j]).
-    # 'row' scales are (V, 1): every slot of a vrow shares column 0 -- the
-    # granularity is a static choice, so the index map is too.
-    if granularity == "block":
-        s_spec = pl.BlockSpec((1, 1),
-                              lambda i, j, row, col, vr, sl: (vr[j], sl[j]))
-    else:
-        s_spec = pl.BlockSpec((1, 1),
-                              lambda i, j, row, col, vr, sl: (vr[j], 0))
+    # one scale per schedule step, gathered here and prefetched to SMEM
+    # with the schedule: 'block' scales are (V, P), read at (vr[j], sl[j]);
+    # 'row' scales are (V, 1), shared by every slot of a vrow
+    s_seq = scales[vrow_seq, slot_seq if granularity == "block" else 0]
     return pl.pallas_call(
         functools.partial(_plan_dds_q_kernel, act=act, bias=bias),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bm, bk),
-                             lambda i, j, row, col, vr, sl: (i, col[j])),
+                             lambda i, j, row, col, vr, sl, sc: (i, col[j])),
                 pl.BlockSpec((1, 1, bn, bk),
-                             lambda i, j, row, col, vr, sl:
+                             lambda i, j, row, col, vr, sl, sc:
                              (vr[j], sl[j], 0, 0)),
-                s_spec,
                 pl.BlockSpec((1, bn),
-                             lambda i, j, row, col, vr, sl: (0, row[j])),
+                             lambda i, j, row, col, vr, sl, sc: (0, row[j])),
             ],
             out_specs=pl.BlockSpec(
-                (bm, bn), lambda i, j, row, col, vr, sl: (i, row[j])),
+                (bm, bn), lambda i, j, row, col, vr, sl, sc: (i, row[j])),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
-    )(row_seq, col_seq, vrow_seq, slot_seq, x, qvalues, scales, b)
+    )(row_seq, col_seq, vrow_seq, slot_seq, s_seq.astype(jnp.float32), x,
+      qvalues, b)
 
 
 def plan_dds_q(x: jax.Array, qvalues: jax.Array, scales: jax.Array,
                schedule, *, n: int, tile: Tuple[int, int],
                granularity: str = "block", bias: jax.Array | None = None,
                act: str | None = None, bm: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Y(M, N) = X(M, K) @ dequant(Q)^T, dequant fused into the block loop.
 
     Same contract as :func:`plan_dds` with the (V, P, bn, bk) values stored
@@ -528,8 +558,8 @@ def plan_dds_q(x: jax.Array, qvalues: jax.Array, scales: jax.Array,
     ('row'). Each tile's partial product is scaled before it joins the VMEM
     accumulator; bias/act fuse into the row-change write as before.
     """
+    check_pallas_tile(tile, interpret)
     m = x.shape[0]
-    bn, bk = tile
     row_seq, col_seq, vrow_seq, slot_seq = schedule
     bm = min(bm, _ceil_mult(m, 8))
     pad = (-m) % bm
@@ -542,13 +572,13 @@ def plan_dds_q(x: jax.Array, qvalues: jax.Array, scales: jax.Array,
                          jnp.asarray(col_seq), jnp.asarray(vrow_seq),
                          jnp.asarray(slot_seq), n=n, tile=tile, bm=bm,
                          granularity=granularity, act=act, bias=has_bias,
-                         interpret=interpret)
+                         interpret=pallas_interpret_default(interpret))
     return y[:m] if pad else y
 
 
 def plan_dds_t(dy: jax.Array, data_rp: jax.Array, t_schedule, *, k: int,
                tile: Tuple[int, int], bm: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """dX(M, K) = dY(M, N) @ W on the transposed schedule (tiles sorted by
     block column); tile values are gathered+transposed per call, like dds_t.
     """
@@ -562,13 +592,14 @@ def plan_dds_t(dy: jax.Array, data_rp: jax.Array, t_schedule, *, k: int,
     if pad:
         dy = jnp.pad(dy, ((0, pad), (0, 0)))
     x = _dds_call(dy, t_data, jnp.asarray(t_row_seq), jnp.asarray(t_col_seq),
-                  pack_static=((k, n), (bk, bn)), bm=bm, interpret=interpret)
+                  pack_static=((k, n), (bk, bn)), bm=bm,
+                  interpret=pallas_interpret_default(interpret))
     return x[:m] if pad else x
 
 
 def plan_sddmm(dy: jax.Array, x: jax.Array, schedule, *,
                tile: Tuple[int, int], out_dtype, bm: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Per-tile gradient dW[j] = dY[:, row_j]^T @ X[:, col_j] over the
     schedule order. Returns (nnzt, bn, bk); all schedule tiles are real
     (the plan keeps no padding tiles in its vrow/slot lists)."""
@@ -583,4 +614,4 @@ def plan_sddmm(dy: jax.Array, x: jax.Array, schedule, *,
     k = x.shape[1]
     return _sddmm_call(dy, x, jnp.asarray(row_seq), jnp.asarray(col_seq),
                        pack_static=((n, k), tile, out_dtype),
-                       bm=bm, interpret=interpret)
+                       bm=bm, interpret=pallas_interpret_default(interpret))

@@ -550,12 +550,6 @@ def plan_matmul(x: jax.Array, data_rp: jax.Array, plan: RowPackPlan):
 # row order, spill vrows accumulate into the same VMEM scratch as their home
 # row and the segment-sum disappears into the row-change write.
 
-def pallas_interpret_default() -> bool:
-    """Kernels compile on TPU; everywhere else interpret mode is the
-    correctness oracle (docs/PERF.md: orders of magnitude slower)."""
-    return jax.default_backend() != "tpu"
-
-
 @functools.lru_cache(maxsize=None)
 def plan_kernel_sequence(plan: RowPackPlan):
     """Forward tile visitation schedule for the plan-consuming kernel.
@@ -616,8 +610,7 @@ def plan_linear_pallas(x, data_rp, plan: RowPackPlan):
 def _plan_pallas_fwd_impl(x, data_rp, plan, bias=None, act=None):
     from repro.kernels.bsr_matmul import plan_dds
     return plan_dds(x, data_rp, plan_kernel_sequence(plan),
-                    n=plan.shape[0], tile=plan.tile, bias=bias, act=act,
-                    interpret=pallas_interpret_default())
+                    n=plan.shape[0], tile=plan.tile, bias=bias, act=act)
 
 
 def _plan_pallas_fwd(x, data_rp, plan):
@@ -627,12 +620,10 @@ def _plan_pallas_fwd(x, data_rp, plan):
 def _plan_pallas_bwd(plan, res, dy):
     from repro.kernels.bsr_matmul import plan_dds_t, plan_sddmm
     x, data_rp = res
-    interpret = pallas_interpret_default()
     dx = plan_dds_t(dy, data_rp, plan_t_sequence(plan),
-                    k=plan.shape[1], tile=plan.tile, interpret=interpret)
+                    k=plan.shape[1], tile=plan.tile)
     seq = plan_kernel_sequence(plan)
-    g_seq = plan_sddmm(dy, x, seq, tile=plan.tile,
-                       out_dtype=jnp.float32, interpret=interpret)
+    g_seq = plan_sddmm(dy, x, seq, tile=plan.tile, out_dtype=jnp.float32)
     # scatter schedule-ordered tile grads back into the row-grouped layout;
     # untouched (padding) slots stay exactly zero, matching slot_mask
     ddata = jnp.zeros(data_rp.shape, jnp.float32)
@@ -846,8 +837,7 @@ def plan_q_linear_pallas(x, qvalues, scales, plan: RowPackPlan, *,
     granularity = "row" if scales.shape[-1] == 1 else "block"
     return plan_dds_q(x, qvalues, scales, plan_kernel_sequence(plan),
                       n=plan.shape[0], tile=plan.tile,
-                      granularity=granularity, bias=bias, act=act,
-                      interpret=pallas_interpret_default())
+                      granularity=granularity, bias=bias, act=act)
 
 
 def plan_q_matmul_pallas(x: jax.Array, qvalues, scales,

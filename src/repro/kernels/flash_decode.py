@@ -31,8 +31,9 @@ split boundaries (tests/test_pallas_serving.py).
 Kernel selection: ``resolved_decode_kernel()`` reads an explicit
 ``decode_kernel_override`` context (set by Servable from the ServingSpec at
 trace time), else the ``REPRO_DECODE_KERNEL`` env (auto|xla|flash), else
-picks flash on TPU and the XLA path everywhere else (interpret mode stays a
-correctness oracle, not a serving path -- docs/PERF.md).
+picks flash on TPU for shapes it compiles (``flash_decode_aligned``) and the
+XLA path everywhere else (interpret mode stays a correctness oracle, not a
+serving path -- docs/PERF.md).
 """
 from __future__ import annotations
 
@@ -45,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.bsr_matmul import pallas_interpret_default
+
 NEG_INF = -1e30
 
 _ENV = "REPRO_DECODE_KERNEL"
@@ -52,19 +55,34 @@ DECODE_KERNELS = ("auto", "xla", "flash")
 _OVERRIDE: list = []
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def flash_decode_aligned(hkv: int, d: int, chunk: int = 8) -> bool:
+    """Whether the compiled kernels can take this decode shape: each grid
+    step streams one KV head's (chunk, D) slice of the (.., T, Hkv*D) cache
+    view, which the TPU (8, 128) tiling accepts when D is a lane multiple
+    (or spans the whole row, Hkv == 1) and the chunk a sublane multiple."""
+    return (hkv == 1 or d % 128 == 0) and chunk % 8 == 0
 
 
-def resolved_decode_kernel() -> str:
-    """'xla' or 'flash': innermost override context > env > platform."""
+def _check_aligned(hkv, d, chunk, interpret):
+    if not interpret and not flash_decode_aligned(hkv, d, chunk):
+        raise ValueError(
+            f"flash decode cannot compile for Hkv={hkv}, head_dim={d}, "
+            f"KV chunk {chunk}: head_dim must be a multiple of 128 (or one "
+            f"KV head) and the chunk/page a multiple of 8; use "
+            f"decode_kernel='xla'")
+
+
+def resolved_decode_kernel(hkv: int = 1, d: int = 128, chunk: int = 8) -> str:
+    """'xla' or 'flash': innermost override context > env > platform.
+    'auto' picks flash on TPU for shapes the compiled kernel takes."""
     if _OVERRIDE:
         return _OVERRIDE[-1]
     kind = os.environ.get(_ENV, "").strip() or "auto"
     if kind not in DECODE_KERNELS:
         raise ValueError(f"{_ENV}={kind!r}; expected one of {DECODE_KERNELS}")
     if kind == "auto":
-        return "flash" if jax.default_backend() == "tpu" else "xla"
+        return ("flash" if jax.default_backend() == "tpu"
+                and flash_decode_aligned(hkv, d, chunk) else "xla")
     return kind
 
 
@@ -100,7 +118,9 @@ def _flash_decode_kernel(*refs, n_prefetch, num_split, window, scale):
 
     refs = (*scalar_prefetch, q, k, v, kvp, o, m_scratch, l_scratch, acc).
     prefetch[0] is the per-slot position vector; the paged variant adds the
-    flattened page table (consumed only by the BlockSpec index maps).
+    flattened page table (consumed only by the BlockSpec index maps). k/v
+    blocks are one KV head's (1, ck, D) slice of the (.., ck, Hkv*D) cache
+    view, kvp one split's (1, 1, ck) row of positions.
     """
     pos_ref = refs[0]
     q_ref, k_ref, v_ref, kvp_ref, o_ref, m_ref, l_ref, acc_ref = \
@@ -115,12 +135,12 @@ def _flash_decode_kernel(*refs, n_prefetch, num_split, window, scale):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]                                   # (G, D)
-    k = k_ref[0, :, 0, :]                             # (ck, D)
-    v = v_ref[0, :, 0, :]
+    k = k_ref[0]                                      # (ck, D)
+    v = v_ref[0]
     s_ = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale   # (G, ck)
-    kvp = kvp_ref[...]                                # (1, ck)
+    kvp = kvp_ref[0]                                  # (1, ck)
     pos = pos_ref[b]
     ok = (kvp >= 0) & (kvp <= pos)
     if window > 0:
@@ -152,6 +172,11 @@ def _flash_decode_call(q4, k, v, kvp, pos, *, window, num_split, interpret):
     t = k.shape[1]
     ck = t // num_split
     grid = (b, hkv, num_split)
+    # (B, T, Hkv, D) -> (B, T, Hkv*D) and positions -> (B*S, 1, ck) are free
+    # reshapes that give every block TPU-tileable trailing dims
+    k = k.reshape(b, t, hkv * d)
+    v = v.reshape(b, t, hkv * d)
+    kvp = kvp.reshape(b * num_split, 1, ck)
     return pl.pallas_call(
         functools.partial(_flash_decode_kernel, n_prefetch=1,
                           num_split=num_split, window=window,
@@ -161,9 +186,10 @@ def _flash_decode_call(q4, k, v, kvp, pos, *, window, num_split, interpret):
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d), lambda b, h, s, pos: (b, h, 0, 0)),
-                pl.BlockSpec((1, ck, 1, d), lambda b, h, s, pos: (b, s, h, 0)),
-                pl.BlockSpec((1, ck, 1, d), lambda b, h, s, pos: (b, s, h, 0)),
-                pl.BlockSpec((1, ck), lambda b, h, s, pos: (b, s)),
+                pl.BlockSpec((1, ck, d), lambda b, h, s, pos: (b, s, h)),
+                pl.BlockSpec((1, ck, d), lambda b, h, s, pos: (b, s, h)),
+                pl.BlockSpec((1, 1, ck),
+                             lambda b, h, s, pos: (b * num_split + s, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
                                    lambda b, h, s, pos: (b, h, 0, 0)),
@@ -182,9 +208,10 @@ def flash_decode(q, k_cache, v_cache, kv_positions, pos, *, window=0,
 
     Same contract as decode_attention (ragged per-slot ``pos``, shared or
     per-slot ``kv_positions``, ring-cache ``window``). ``kv_split`` chunks
-    the KV axis (T is padded with masked slots to a multiple); matching
-    split boundaries make two runs of this kernel -- e.g. over a paged
-    cache's gathered view vs ``paged_flash_decode`` -- bit-exact.
+    the KV axis; T is padded with masked slots so every chunk is a multiple
+    of 8 positions. Matching split boundaries make two runs of this kernel
+    -- e.g. over a paged cache's gathered view vs ``paged_flash_decode`` --
+    bit-exact.
     """
     b, _, hq, d = q.shape
     t, hkv = k_cache.shape[1], k_cache.shape[2]
@@ -193,13 +220,13 @@ def flash_decode(q, k_cache, v_cache, kv_positions, pos, *, window=0,
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     kvp = jnp.asarray(kv_positions, jnp.int32)
     kvp = jnp.broadcast_to(kvp[None, :] if kvp.ndim == 1 else kvp, (b, t))
-    pad = (-t) % num_split
+    pad = (-t) % (8 * num_split)
     if pad:
         k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kvp = jnp.pad(kvp, ((0, 0), (0, pad)), constant_values=-1)
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = pallas_interpret_default(interpret)
+    _check_aligned(hkv, d, (t + pad) // num_split, interpret)
     out = _flash_decode_call(q[:, 0].reshape(b, hkv, g, d), k_cache, v_cache,
                              kvp, posv, window=window, num_split=num_split,
                              interpret=interpret)
@@ -214,13 +241,18 @@ def flash_decode(q, k_cache, v_cache, kv_positions, pos, *, window=0,
 def _paged_flash_call(q4, k_pages, v_pages, pt_flat, pm, pos, *, window, npg,
                       interpret):
     b, hkv, g, d = q4.shape
-    ps = k_pages.shape[1]
+    n_pages, ps = k_pages.shape[:2]
     grid = (b, hkv, npg)
+    # same free reshapes as _flash_decode_call: pools -> (pages, ps, Hkv*D),
+    # pos_map -> (B*NP, 1, ps) so one page is one (1, 1, ps) block
+    k_pages = k_pages.reshape(n_pages, ps, hkv * d)
+    v_pages = v_pages.reshape(n_pages, ps, hkv * d)
+    pm = pm.reshape(b * npg, 1, ps)
 
     def page_map(b, h, s, pos, pt):
         # unmapped (-1) pages clip to page 0; their pos_map slots are -1 so
         # every lane of the split is masked before the exp
-        return (jnp.maximum(pt[b * npg + s], 0), 0, h, 0)
+        return (jnp.maximum(pt[b * npg + s], 0), 0, h)
 
     return pl.pallas_call(
         functools.partial(_flash_decode_kernel, n_prefetch=2, num_split=npg,
@@ -231,9 +263,10 @@ def _paged_flash_call(q4, k_pages, v_pages, pt_flat, pm, pos, *, window, npg,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d),
                              lambda b, h, s, pos, pt: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, 1, d), page_map),
-                pl.BlockSpec((1, ps, 1, d), page_map),
-                pl.BlockSpec((1, ps), lambda b, h, s, pos, pt: (b, s)),
+                pl.BlockSpec((1, ps, d), page_map),
+                pl.BlockSpec((1, ps, d), page_map),
+                pl.BlockSpec((1, 1, ps),
+                             lambda b, h, s, pos, pt: (b * npg + s, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
                                    lambda b, h, s, pos, pt: (b, h, 0, 0)),
@@ -262,8 +295,8 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, pos_map, pos, *,
     g = hq // hkv
     assert pos_map.shape == (b, npg * ps), (pos_map.shape, npg, ps)
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = pallas_interpret_default(interpret)
+    _check_aligned(hkv, d, ps, interpret)
     out = _paged_flash_call(q[:, 0].reshape(b, hkv, g, d), k_pages, v_pages,
                             page_table.reshape(-1).astype(jnp.int32),
                             jnp.asarray(pos_map, jnp.int32), posv,

@@ -118,8 +118,7 @@ def bsr_linear(x, data, pack: KernelBSR, backend: str = "gather"):
 
 def _bsr_linear_fwd_impl(x, data, pack, backend):
     if backend == "pallas":
-        return bk.dds(x, _with_data(pack, data),
-                      interpret=jax.default_backend() != "tpu")
+        return bk.dds(x, _with_data(pack, data))
     if backend == "rowpack":
         return _rowpack_matmul(x, data, pack)
     m = _core_bsr_from_pack(data, pack)
@@ -136,10 +135,9 @@ def _bsr_linear_fwd(x, data, pack, backend):
 
 def _bsr_linear_bwd(pack, backend, res, dy):
     x, data = res
-    interp = jax.default_backend() != "tpu"
     if backend == "pallas":
-        dx = bk.dds_t(dy, _with_data(pack, data), interpret=interp)
-        ddata = bk.sddmm(dy, x, _with_data(pack, data), interpret=interp)
+        dx = bk.dds_t(dy, _with_data(pack, data))
+        ddata = bk.sddmm(dy, x, _with_data(pack, data))
     else:
         m = _core_bsr_from_pack(data, pack)
         dx = kref.bsr_matmul_t_gather(dy, m)

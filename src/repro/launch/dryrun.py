@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"     # fake host devices; never the chip
 os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=" +
                            os.environ.get("DRYRUN_DEVICES", "512")).strip()
@@ -32,6 +33,10 @@ from repro.launch.sharding import (batch_shardings, cache_shardings,
 from repro.launch.specs import cache_specs, cell_is_supported, input_specs
 from repro.launch.steps import make_prefill_step, make_serve_step, make_train_step
 from repro.optim.adamw import AdamWConfig, init_opt_state
+
+#: the chip whose peaks the roofline terms are computed against (the cells
+#: compile on fake host devices, so this cannot be read off the backend)
+DEVICE_KIND = "TPU v5 lite"
 
 
 def lower_cell(cfg, shape_name, mesh, opt_cfg=None):
@@ -120,7 +125,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str):
             flops=loop_aware["flops"],
             hbm_bytes=loop_aware["bytes"],
             coll_bytes=coll["total"],
-            n_devices=n_dev, model_flops=model_fl)
+            n_devices=n_dev, model_flops=model_fl, device_kind=DEVICE_KIND)
 
         cell.update(
             status="OK", lower_s=round(t_lower, 1),

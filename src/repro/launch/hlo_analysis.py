@@ -18,10 +18,24 @@ import dataclasses
 import re
 from typing import Dict
 
-# TPU v5e-class constants (assignment-specified)
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+#: 1,600 Gbit/s chip-to-chip interconnect (200 GB/s over 4 links).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12,       # bf16 / chip
+                    "hbm_bw": 819e9,       # bytes/s / chip
+                    "link_bw": 50e9},      # bytes/s / link
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The :data:`PEAKS` row for ``device_kind``; a kind without published
+    peaks is an error, never a silent default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -74,18 +88,22 @@ class Roofline:
     coll_bytes: float            # per-device collective bytes
     n_devices: int
     model_flops: float = 0.0     # 6*N*D-style useful flops (fleet-wide)
+    device_kind: str = dataclasses.field(kw_only=True)  # a PEAKS key
+
+    def __post_init__(self):
+        self.peak = peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peak["flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peak["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / LINK_BW
+        return self.coll_bytes / self.peak["link_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -105,12 +123,13 @@ class Roofline:
         t = max(self.t_compute, self.t_memory, self.t_collective)
         if t <= 0:
             return 0.0
-        return self.model_flops / (self.n_devices * PEAK_FLOPS * t)
+        return self.model_flops / (self.n_devices * self.peak["flops"] * t)
 
     def to_dict(self) -> dict:
         return {
             "flops_per_dev": self.flops, "hbm_bytes_per_dev": self.hbm_bytes,
             "coll_bytes_per_dev": self.coll_bytes, "n_devices": self.n_devices,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective, "bottleneck": self.bottleneck,

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.compat import shard_map
 
 
 def pipeline_apply(layer_fn, stage_params, x_microbatches, mesh,
@@ -33,7 +32,7 @@ def pipeline_apply(layer_fn, stage_params, x_microbatches, mesh,
     m = x_microbatches.shape[0]
     total_ticks = m + n_stages - 1
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis), P(None)), out_specs=P(None),
              check_vma=False)
     def run(params_stage, xs):

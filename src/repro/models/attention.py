@@ -660,7 +660,8 @@ def apply_attention(p, x, cfg, *, positions, window=0, cache=None, pos=None,
                                active)
         new_cache = {"k_pages": kp, "v_pages": vp, "pos_map": pm,
                      "page_table": pt}
-        if resolved_decode_kernel() == "flash":
+        if resolved_decode_kernel(cfg.n_kv_heads, hd,
+                                  kp.shape[1]) == "flash":
             # the split-K kernel reads pages in place via the prefetched
             # table -- no per-step dense-view gather
             out = paged_flash_decode(q, kp, vp, pt, pm, posv, window=window)
@@ -704,7 +705,7 @@ def apply_attention(p, x, cfg, *, positions, window=0, cache=None, pos=None,
                 cv = _masked_row_write(cache["v"], rows, slot, v[:, 0],
                                        active)
                 new_cache = {"k": ck, "v": cv, "pos_map": pm}
-                if resolved_decode_kernel() == "flash":
+                if resolved_decode_kernel(cfg.n_kv_heads, hd) == "flash":
                     out = flash_decode(q, ck, cv, pm, posv, window=window)
                 else:
                     out = decode_attention(q, ck, cv, pm, posv,

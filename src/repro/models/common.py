@@ -160,8 +160,7 @@ def linear(p, x, pack=None, backend=None):
             from repro.kernels.bsr_matmul import masked_matmul
             lead = x.shape[:-1]
             y = masked_matmul(x.reshape(-1, x.shape[-1]), p["w"],
-                              jnp.asarray(pack.tile_mask), tile=pack.tile,
-                              interpret=jax.default_backend() != "tpu")
+                              jnp.asarray(pack.tile_mask), tile=pack.tile)
             return y.reshape(*lead, pack.shape[0])
         from repro.kernels.ops import bsr_matmul  # local import, cycle-free
         from repro.kernels.bsr_matmul import KernelBSR

@@ -91,9 +91,7 @@ def make_compressed_sync(mesh, axis_names, cfg: CompressionConfig):
     from functools import partial
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime.compat import shard_map
-
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=(P(), P()), check_vma=False)
     def sync(g, e):
         return compressed_allreduce(g, e, cfg, axis_names)

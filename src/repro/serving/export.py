@@ -149,7 +149,9 @@ def _realize_backend(pack, data, backend: str,
                                      backend="plan")
         return plan, pack_plan_data(plan, data)
     if backend in ("plan_pallas", "plan_pallas_q8"):
+        from repro.kernels.bsr_matmul import check_pallas_tile
         from repro.kernels.exec_plan import PlanChoice
+        check_pallas_tile(pack.tile)
         plan = plan_for_pack(pack, registry)
         if backend == "plan_pallas_q8" or quant != "none":
             return quantize_for_plan(plan, data,

@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.hlo_cost import HloCostModel, parse_module
+from repro.launch.mesh import make_mesh
 
 
 def _compile(fn, *specs, **jit_kw):
@@ -55,7 +56,7 @@ def test_nested_loops_multiply():
 
 
 def test_collectives_counted_with_trip_multiplier():
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     sh = NamedSharding(mesh, P("d"))
 
     def fn(x):
@@ -106,3 +107,14 @@ ENTRY %main (x: f32[4,4]) -> f32[4,4] {
     t = m.total()
     dot_flops = 10 * 2 * 4 * 4 * 4          # 10 trips x dot(4x4x4)
     assert dot_flops <= t.flops <= dot_flops + 10 * 4  # + add/compare per trip
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch.hlo_analysis import PEAKS, Roofline
+    r = Roofline(197e12, 819e9, 0.0, 1, device_kind="TPU v5 lite")
+    assert r.t_compute == pytest.approx(1.0)
+    assert r.t_memory == pytest.approx(1.0)
+    assert r.to_dict()["device_kind"] == "TPU v5 lite"
+    assert set(PEAKS["TPU v5 lite"]) == {"flops", "hbm_bw", "link_bw"}
+    with pytest.raises(ValueError, match="no published peaks"):
+        Roofline(1.0, 1.0, 0.0, 1, device_kind="cpu")

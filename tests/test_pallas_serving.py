@@ -162,6 +162,75 @@ def test_plan_data_roundtrip_through_pallas_grad():
                                rtol=2e-5, atol=1e-4)
 
 
+def test_pallas_entries_pick_interpret_from_platform():
+    """Every Pallas entry defaults to interpret=None: the platform decides
+    (compiled on TPU, interpret elsewhere), so no caller can end up in the
+    interpreter on the chip by leaving the flag out."""
+    import importlib
+    import inspect
+    bm = importlib.import_module("repro.kernels.bsr_matmul")
+    for fn in (bm.dds, bm.dds_t, bm.sddmm, bm.masked_matmul, bm.plan_dds,
+               bm.plan_dds_q, bm.plan_dds_t, bm.plan_sddmm, flash_decode,
+               paged_flash_decode):
+        assert inspect.signature(fn).parameters["interpret"].default is None
+    on_tpu = jax.default_backend() == "tpu"
+    assert bm.pallas_interpret_default() is not on_tpu
+    assert bm.pallas_interpret_default(True) is True
+    assert bm.pallas_interpret_default(False) is False
+    # on this platform the default call runs (interpret mode off-TPU)
+    rng = np.random.RandomState(12)
+    w, plan, data_rp = _plan_pack(rng, 32, 48, (16, 16), 0.5)
+    x = jnp.asarray(rng.randn(5, 48).astype(np.float32))
+    y = bm.plan_dds(x, data_rp, plan_kernel_sequence(plan), n=32,
+                    tile=(16, 16))
+    np.testing.assert_allclose(np.asarray(y), x @ w.T, rtol=1e-5, atol=1e-5)
+
+
+def test_unaligned_tile_refused_when_kernels_compile(monkeypatch, tmp_path):
+    """The paper's 32x1 tile cannot run through a compiled Pallas backend:
+    it raises a ValueError at export (and at the kernel) instead of dying
+    in Mosaic lowering, and the TPU autotuner drops the Pallas arms."""
+    import importlib
+
+    from repro.configs.registry import get_config
+    from repro.kernels import autotune
+    from repro.models import init_model
+    from repro.serving import ServingSpec, prepare_servable
+
+    bm = importlib.import_module("repro.kernels.bsr_matmul")
+    assert bm.pallas_tile_supported((128, 128))
+    assert not bm.pallas_tile_supported((32, 1))
+    assert not bm.pallas_tile_supported((32, 128))
+    bm.check_pallas_tile((32, 1), interpret=True)     # interpret takes any
+    with pytest.raises(ValueError, match="multiples of 128"):
+        bm.check_pallas_tile((32, 1), interpret=False)
+    rng = np.random.RandomState(13)
+    w, plan, data_rp = _plan_pack(rng, 64, 32, (32, 1), 0.5)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        bm.plan_dds(jnp.ones((8, 32)), data_rp, plan_kernel_sequence(plan),
+                    n=64, tile=(32, 1), interpret=False)
+
+    compiled = lambda interpret=None: False if interpret is None \
+        else bool(interpret)
+    monkeypatch.setattr(bm, "pallas_interpret_default", compiled)
+    monkeypatch.setattr(autotune, "pallas_interpret_default", compiled)
+    cfg = get_config("bert_base", smoke=True)
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        prepare_servable(params, cfg, ServingSpec(
+            tile=(32, 1), sparsity=0.5, prune="oneshot",
+            backend="plan_pallas"))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        prepare_servable(params, cfg, ServingSpec(
+            tile=(32, 1), sparsity=0.5, prune="oneshot",
+            backend="plan_pallas", pack_quant="int8"))
+    pk = pack_bsr(_sparse_weight(rng, 64, 32, (32, 1), 0.5), (32, 1))
+    choice = autotune.choose_backend(
+        pk, 8, stub=True, quant="int8",
+        cache=autotune.AutotuneCache(str(tmp_path / "at.json")))
+    assert not set(choice.costs) & set(autotune.INTERPRET_ONLY)
+
+
 # --------------------------------------------------------------------------
 # split-K flash decode vs decode_attention
 # --------------------------------------------------------------------------

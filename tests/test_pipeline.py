@@ -11,9 +11,10 @@ CODE = """
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.launch.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((4,), ('pipe',))
+mesh = make_mesh((4,), ('pipe',))
 rng = np.random.RandomState(0)
 S, MB, D = 4, 8, 16
 ws = jnp.asarray(rng.randn(S, D, D).astype(np.float32) * 0.3)

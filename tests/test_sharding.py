@@ -24,6 +24,11 @@ def _mesh():
     return make_mesh((1, 1), ("data", "model"))
 
 
+def test_make_mesh_axes_are_auto():
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
+
+
 class TestSpecRules:
     def test_column_vs_row_parallel(self):
         mesh = make_mesh((1, 1), ("data", "model"))
@@ -35,10 +40,7 @@ class TestSpecRules:
 
     def test_indivisible_dims_replicate(self):
         # abstract mesh: spec rules shouldn't need real devices
-        try:   # modern signature: (axis_sizes, axis_names)
-            wide = jax.sharding.AbstractMesh((1, 16), ("data", "model"))
-        except TypeError:   # older JAX: one tuple of (name, size) pairs
-            wide = jax.sharding.AbstractMesh((("data", 1), ("model", 16)))
+        wide = jax.sharding.AbstractMesh((1, 16), ("data", "model"))
         spec = spec_for_param("blocks/0/attn/wk/w", (2, 100, 4096), wide)
         assert spec[1] is None     # 100 % 16 != 0 -> replicated
         assert spec[2] == "data"   # in-dim divisible by data axis -> FSDP
@@ -99,7 +101,8 @@ def test_dryrun_subprocess_smoke(tmp_path):
         "import jax\n"
         "from repro.configs.registry import get_config\n"
         "from repro.launch.dryrun import lower_cell\n"
-        "mesh = jax.make_mesh((4,4),('data','model'))\n"
+        "from repro.launch.mesh import make_mesh\n"
+        "mesh = make_mesh((4,4),('data','model'))\n"
         "c = lower_cell(get_config('whisper_base', smoke=True), 'train_4k', mesh)\n"
         "compiled = c.compile()\n"
         "print('MEM', compiled.memory_analysis() is not None)\n"

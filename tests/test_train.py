@@ -13,6 +13,7 @@ from repro.checkpoint.store import CheckpointStore
 from repro.configs.registry import get_config
 from repro.core.sparsity import SparsityConfig, actual_sparsity
 from repro.data.pipeline import DataConfig, DataPipeline
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, Trainer
 from repro.optim.adamw import AdamWConfig
 from repro.optim.compression import (CompressionConfig, compress,
@@ -21,7 +22,7 @@ from repro.runtime.fault_tolerance import (FaultInjector,
                                            FaultToleranceConfig,
                                            StragglerMonitor)
 
-single_mesh = lambda: jax.make_mesh((1, 1), ("data", "model"))
+single_mesh = lambda: make_mesh((1, 1), ("data", "model"))
 
 
 def _tcfg(tmp, **kw):
@@ -147,7 +148,7 @@ class TestCompression:
 
     def test_compressed_allreduce_under_shard_map(self):
         from repro.optim.compression import make_compressed_sync
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         ccfg = CompressionConfig(block_shape=(8, 128), density=1.0,
                                  min_size=0)
         rng = np.random.RandomState(2)
@@ -190,11 +191,12 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
 import jax, numpy as np
 from repro.configs.registry import get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, Trainer
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.fault_tolerance import FaultToleranceConfig
 cfg = get_config('deepseek_7b', smoke=True)
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = make_mesh((2, 2), ('data', 'model'))
 tcfg = TrainConfig(n_steps=6, ckpt_dir={str(tmp_path)!r},
                    opt=AdamWConfig(peak_lr=5e-3, warmup_steps=2,
                                    total_steps=50, weight_decay=0.0),
