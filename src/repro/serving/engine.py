@@ -85,6 +85,7 @@ equal to sequential decode (all other families are; tests/test_engine.py).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import logging
 import os
@@ -99,6 +100,7 @@ from repro.models import api as model_api
 from repro.models.common import PagedLayout
 from repro.models.sampling import sample_token_row
 from repro.runtime import chaos as chaos_mod
+from repro.runtime.trace import timed
 from repro.serving.paging import PagePool, PagePoolExhausted, pages_needed
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.spec import KV_LAYOUTS, OVERFLOW_POLICIES, SchedSpec
@@ -216,7 +218,8 @@ class EngineStats:
     # wall-clock breakdown of the serving loop (seconds): prompt prefill
     # (compute + slot insert), decode windows (device call until outputs
     # materialize on host), and host-side sync work (token drain,
-    # callbacks, slot recycling) -- benchmarks/serving_bench.py reports it
+    # callbacks, slot recycling) -- the summed durations of the
+    # repro.engine.admit / .window / .sync spans (runtime/trace.py)
     prefill_s: float = 0.0
     decode_s: float = 0.0
     sync_s: float = 0.0
@@ -716,66 +719,105 @@ class ServingEngine:
         prefills over prompt + already-generated tokens, continuing exactly
         where it stopped. Any failure here fails ONLY this request: the
         slot is restored and the engine keeps serving."""
-        t0 = time.perf_counter()
-        slot = None
-        try:
-            if self._chaos is not None:
-                self._chaos.fire(chaos_mod.SITE_ALLOC, engine=self,
-                                 request=req)
-            slot = self._free.pop(0)
-            seq = req.prompt if not req.tokens else np.concatenate(
-                [req.prompt, np.asarray(req.tokens, np.int32)])
-            length = int(seq.size)
-            bucket = self._bucket(length)
+        slot, err = None, None
+        with self._admit_span(req) as sp:
+            try:
+                if self._chaos is not None:
+                    self._chaos.fire(chaos_mod.SITE_ALLOC, engine=self,
+                                     request=req)
+                slot = self._free.pop(0)
+                seq = req.prompt if not req.tokens else np.concatenate(
+                    [req.prompt, np.asarray(req.tokens, np.int32)])
+                length = int(seq.size)
+                bucket = self._bucket(length)
 
-            if self._chaos is not None:
-                self._chaos.fire(chaos_mod.SITE_PREFILL, engine=self,
-                                 request=req)
-            if self.cfg.family == "audio":
-                sub = model_api.init_cache(
-                    self.servable.params, self.cfg, 1, self.cache_len,
-                    frames=jnp.asarray(req.frames)[None]
-                    if np.ndim(req.frames) == 2 else jnp.asarray(req.frames))
+                if self._chaos is not None:
+                    self._chaos.fire(chaos_mod.SITE_PREFILL, engine=self,
+                                     request=req)
+                if self.cfg.family == "audio":
+                    sub = model_api.init_cache(
+                        self.servable.params, self.cfg, 1, self.cache_len,
+                        frames=jnp.asarray(req.frames)[None]
+                        if np.ndim(req.frames) == 2
+                        else jnp.asarray(req.frames))
+                else:
+                    sub = self._sub_template
+                toks = np.zeros((bucket,), np.int32)
+                toks[:length] = seq
+                pos_seq = np.full((bucket,), -1, np.int32)
+                pos_seq[:length] = np.arange(length)
+                sub, logits = self._prefill(self.servable.params, sub,
+                                            jnp.asarray(toks),
+                                            jnp.asarray(pos_seq),
+                                            jnp.int32(length))
+                self.cache = self._write_slot(self.cache, jnp.int32(slot),
+                                              sub)
+                row = np.asarray(logits[length - 1])  # once per admission
+            except Exception as e:  # noqa: BLE001 -- isolate to this request
+                self._restore_slot(slot)
+                err = e
             else:
-                sub = self._sub_template
-            toks = np.zeros((bucket,), np.int32)
-            toks[:length] = seq
-            pos_seq = np.full((bucket,), -1, np.int32)
-            pos_seq[:length] = np.arange(length)
-            sub, logits = self._prefill(self.servable.params, sub,
-                                        jnp.asarray(toks),
-                                        jnp.asarray(pos_seq),
-                                        jnp.int32(length))
-            self.cache = self._write_slot(self.cache, jnp.int32(slot), sub)
-            row = np.asarray(logits[length - 1])    # once per admission
-        except Exception as e:  # noqa: BLE001 -- isolate to this request
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
-            log.warning("admission of request %d failed (%s: %s)",
-                        req.req_id, type(e).__name__, e)
-            self._finalize(req, "failed", FailureReason(
-                FailureReason.PREFILL_ERROR, f"{type(e).__name__}: {e}"))
-            return True
-
-        self.stats.prefills += 1
-        self.stats.prefilled_tokens += length
-        self.stats.bucket_hits[bucket] += 1
-        if not np.all(np.isfinite(row)):
-            # poisoned before the first decode: quarantine at admission
-            self.cache = self._free_slot(self.cache, jnp.int32(slot))
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
+                sp.set(tokens=length)
+                self.stats.prefills += 1
+                self.stats.prefilled_tokens += length
+                self.stats.bucket_hits[bucket] += 1
+                finite = bool(np.all(np.isfinite(row)))
+                if finite:
+                    self._activate(req, slot, length)
+                    tok = sample_token_row(row, self._key, slot, length - 1,
+                                           temperature=self.temperature,
+                                           top_k=self.top_k)
+                else:
+                    # poisoned before the first decode: quarantine at
+                    # admission
+                    self.cache = self._free_slot(self.cache, jnp.int32(slot))
+                    self._restore_slot(slot)
+        if err is not None:
+            self._fail_admission(req, err)
+        elif not finite:
             self._finalize(req, "failed", FailureReason(
                 FailureReason.NONFINITE_LOGITS,
                 f"non-finite prefill logits at position {length - 1}"))
-            return True
+        else:
+            self._emit(req, int(tok), row)
+        return True
 
-        self._activate(req, slot, length)
-        tok = sample_token_row(row, self._key, slot, length - 1,
-                               temperature=self.temperature,
-                               top_k=self.top_k)
-        self.stats.prefill_s += time.perf_counter() - t0
-        self._emit(req, int(tok), row)
+    @contextlib.contextmanager
+    def _admit_span(self, req: EngineRequest):
+        """The ``repro.engine.admit`` span of one admission or prefill pass
+        of ``req``, timed into ``stats.prefill_s``; ``tokens``, set at exit,
+        the tokens prefilled. The pass that makes a never-preempted request
+        active for the first time also carries ``wait_us``, its time in the
+        queue: a parked attempt, a later chunk and a resume carry none."""
+        first = (req.status != "active" and not req.tokens
+                 and req.n_preempted == 0)
+        wait = time.monotonic() - req.submitted_at
+        with timed(self.stats, "prefill_s", "repro.engine.admit",
+                   req=req.req_id, tokens=0) as sp:
+            yield sp
+            if first and req.status == "active":
+                sp.set(wait_us=int(wait * 1e6))
+
+    def _fail_admission(self, req: EngineRequest, err: Exception) -> None:
+        log.warning("admission of request %d failed (%s: %s)",
+                    req.req_id, type(err).__name__, err)
+        self._finalize(req, "failed", FailureReason(
+            FailureReason.PREFILL_ERROR, f"{type(err).__name__}: {err}"))
+
+    def _park_or_fail(self, req: EngineRequest,
+                      err: PagePoolExhausted) -> bool:
+        """Paged backpressure: park ``req`` at the queue FRONT while active
+        requests can still release pages (False, the ``_admit`` contract),
+        else fail it (True)."""
+        if self._active:
+            req.status = "queued"
+            self._queue.appendleft(req)
+            log.info("parking request %d on page pressure (%s)",
+                     req.req_id, err)
+            return False
+        self._finalize(req, "failed", FailureReason(
+            FailureReason.KV_PAGES,
+            f"{err} with no active requests to drain"))
         return True
 
     def _page_row(self, pages: List[int]):
@@ -804,139 +846,126 @@ class ServingEngine:
         sharers. A preempted request whose pages were retained re-attaches
         them with NO prefill at all. Pool exhaustion is backpressure (park
         at the queue front / structured shed), never a crash."""
-        t0 = time.perf_counter()
-        slot = None
+        slot, err = None, None
         held: List[int] = []            # pages owned by THIS admission
-        try:
-            if self._chaos is not None:
-                self._chaos.fire(chaos_mod.SITE_ALLOC, engine=self,
-                                 request=req)
-            slot = self._free.pop(0)
+        with self._admit_span(req) as sp:
+            try:
+                if self._chaos is not None:
+                    self._chaos.fire(chaos_mod.SITE_ALLOC, engine=self,
+                                     request=req)
+                slot = self._free.pop(0)
 
-            saved = self._saved_pages.pop(req.req_id, None)
-            if saved is not None:
-                # preempt-resume via page retention: the victim's pages
-                # were never released, so restoring the page table + pos
-                # map resumes it bit-exactly with zero prefill work
-                pages, resume_len = saved
-                held = pages
-                self.cache = self._restore_paged(
-                    self.cache, jnp.int32(slot), self._page_row(pages),
-                    jnp.int32(resume_len))
-                self._activate(req, slot, resume_len, pages)
-                self._tokens[slot, 0] = req.tokens[-1]
-                self._pos[slot] = resume_len
-                self._remaining[slot] = \
-                    req.max_new_tokens - req.n_generated
-                self.stats.page_resumes += 1
-                self.stats.prefill_s += time.perf_counter() - t0
-                return True
+                saved = self._saved_pages.pop(req.req_id, None)
+                if saved is not None:
+                    # preempt-resume via page retention: the victim's pages
+                    # were never released, so restoring the page table +
+                    # pos map resumes it bit-exactly with zero prefill work
+                    pages, resume_len = saved
+                    held = pages
+                    self.cache = self._restore_paged(
+                        self.cache, jnp.int32(slot), self._page_row(pages),
+                        jnp.int32(resume_len))
+                    self._activate(req, slot, resume_len, pages)
+                    self._tokens[slot, 0] = req.tokens[-1]
+                    self._pos[slot] = resume_len
+                    self._remaining[slot] = \
+                        req.max_new_tokens - req.n_generated
+                    self.stats.page_resumes += 1
+                    return True
 
-            seq = req.prompt if not req.tokens else np.concatenate(
-                [req.prompt, np.asarray(req.tokens, np.int32)])
-            length = int(seq.size)
-            need = pages_needed(
-                min(length + req.max_new_tokens, self.cache_len),
-                self.kv_page_size)
-            shared: List[int] = []
-            if self._can_share and not req.tokens:
-                # cap the match at length-1 so a fully-cached prompt still
-                # prefills >= 1 suffix token (the forward pass must have a
-                # position to produce next-token logits from)
-                shared = self._prefix_cache.match(seq, limit=length - 1)
-                held = held + shared
-            start = len(shared) * self.kv_page_size
-            fresh = self._reserve_pages(need - len(shared))
-            held = held + fresh
-            pages = shared + fresh
-
-            if self._chaos is not None:
-                self._chaos.fire(chaos_mod.SITE_PREFILL, engine=self,
-                                 request=req)
-            if start > 0:
-                # prefix hit: attach the pages, then prefill ONLY the
-                # suffix against the resident shared prefix (masked
-                # attention; write positions never land in shared full
-                # pages, so sharing is copy-on-write by construction)
-                suffix = seq[start:]
-                slen = int(suffix.size)
-                bucket = self._bucket(slen)
-                toks = np.zeros((bucket,), np.int32)
-                toks[:slen] = suffix
-                self.cache = self._restore_paged(
-                    self.cache, jnp.int32(slot), self._page_row(pages),
-                    jnp.int32(start))
-                self.cache, logits = self._suffix_prefill(
-                    self.servable.params, self.cache, jnp.asarray(toks),
-                    jnp.int32(slot), jnp.int32(start), jnp.int32(slen))
-                row = np.asarray(logits[slen - 1])
-                self.stats.prefix_hit_tokens += start
-                self.stats.prefilled_tokens += slen
-            else:
-                bucket = self._bucket(length)
-                toks = np.zeros((bucket,), np.int32)
-                toks[:length] = seq
-                pos_seq = np.full((bucket,), -1, np.int32)
-                pos_seq[:length] = np.arange(length)
-                sub, logits = self._prefill(
-                    self.servable.params, self._sub_template,
-                    jnp.asarray(toks), jnp.asarray(pos_seq),
-                    jnp.int32(length))
-                self.cache = self._write_paged(
-                    self.cache, jnp.int32(slot), sub, self._page_row(pages))
-                row = np.asarray(logits[length - 1])
-                self.stats.prefilled_tokens += length
+                seq = req.prompt if not req.tokens else np.concatenate(
+                    [req.prompt, np.asarray(req.tokens, np.int32)])
+                length = int(seq.size)
+                need = pages_needed(
+                    min(length + req.max_new_tokens, self.cache_len),
+                    self.kv_page_size)
+                shared: List[int] = []
                 if self._can_share and not req.tokens:
-                    # publish the prompt's FULL pages (strictly below the
-                    # prompt length -- the partial tail page is mutable)
-                    self._prefix_cache.insert(
-                        seq, pages[:length // self.kv_page_size])
-        except PagePoolExhausted as e:
-            if held:
-                self._pool.release(held)
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
-            if self._active:
-                # actives will release pages as they finish: park at the
-                # queue FRONT and let the scheduler retry next sync point
-                req.status = "queued"
-                self._queue.appendleft(req)
-                log.info("parking request %d on page pressure (%s)",
-                         req.req_id, e)
-                return False
-            self._finalize(req, "failed", FailureReason(
-                FailureReason.KV_PAGES,
-                f"{e} with no active requests to drain"))
-            return True
-        except Exception as e:  # noqa: BLE001 -- isolate to this request
-            if held:
-                self._pool.release(held)
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
-            log.warning("admission of request %d failed (%s: %s)",
-                        req.req_id, type(e).__name__, e)
-            self._finalize(req, "failed", FailureReason(
-                FailureReason.PREFILL_ERROR, f"{type(e).__name__}: {e}"))
-            return True
+                    # cap the match at length-1 so a fully-cached prompt
+                    # still prefills >= 1 suffix token (the forward pass
+                    # must have a position to produce next-token logits
+                    # from)
+                    shared = self._prefix_cache.match(seq, limit=length - 1)
+                    held = held + shared
+                start = len(shared) * self.kv_page_size
+                fresh = self._reserve_pages(need - len(shared))
+                held = held + fresh
+                pages = shared + fresh
 
-        self.stats.prefills += 1
-        self.stats.bucket_hits[bucket] += 1
-        if not np.all(np.isfinite(row)):
-            self._pool.release(held)
-            self.cache = self._free_slot(self.cache, jnp.int32(slot))
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
+                if self._chaos is not None:
+                    self._chaos.fire(chaos_mod.SITE_PREFILL, engine=self,
+                                     request=req)
+                if start > 0:
+                    # prefix hit: attach the pages, then prefill ONLY the
+                    # suffix against the resident shared prefix (masked
+                    # attention; write positions never land in shared full
+                    # pages, so sharing is copy-on-write by construction)
+                    suffix = seq[start:]
+                    slen = int(suffix.size)
+                    bucket = self._bucket(slen)
+                    toks = np.zeros((bucket,), np.int32)
+                    toks[:slen] = suffix
+                    self.cache = self._restore_paged(
+                        self.cache, jnp.int32(slot), self._page_row(pages),
+                        jnp.int32(start))
+                    self.cache, logits = self._suffix_prefill(
+                        self.servable.params, self.cache, jnp.asarray(toks),
+                        jnp.int32(slot), jnp.int32(start), jnp.int32(slen))
+                    row = np.asarray(logits[slen - 1])
+                    self.stats.prefix_hit_tokens += start
+                    self.stats.prefilled_tokens += slen
+                else:
+                    slen = length
+                    bucket = self._bucket(length)
+                    toks = np.zeros((bucket,), np.int32)
+                    toks[:length] = seq
+                    pos_seq = np.full((bucket,), -1, np.int32)
+                    pos_seq[:length] = np.arange(length)
+                    sub, logits = self._prefill(
+                        self.servable.params, self._sub_template,
+                        jnp.asarray(toks), jnp.asarray(pos_seq),
+                        jnp.int32(length))
+                    self.cache = self._write_paged(
+                        self.cache, jnp.int32(slot), sub,
+                        self._page_row(pages))
+                    row = np.asarray(logits[length - 1])
+                    self.stats.prefilled_tokens += length
+                    if self._can_share and not req.tokens:
+                        # publish the prompt's FULL pages (strictly below
+                        # the prompt length -- the partial tail page is
+                        # mutable)
+                        self._prefix_cache.insert(
+                            seq, pages[:length // self.kv_page_size])
+            except Exception as e:  # noqa: BLE001 -- isolate to this request
+                # PagePoolExhausted included: backpressure, below
+                if held:
+                    self._pool.release(held)
+                self._restore_slot(slot)
+                err = e
+            else:
+                sp.set(tokens=slen)
+                self.stats.prefills += 1
+                self.stats.bucket_hits[bucket] += 1
+                finite = bool(np.all(np.isfinite(row)))
+                if finite:
+                    self._activate(req, slot, length, pages)
+                    tok = sample_token_row(row, self._key, slot, length - 1,
+                                           temperature=self.temperature,
+                                           top_k=self.top_k)
+                else:
+                    self._pool.release(held)
+                    self.cache = self._free_slot(self.cache, jnp.int32(slot))
+                    self._restore_slot(slot)
+        if isinstance(err, PagePoolExhausted):
+            return self._park_or_fail(req, err)
+        if err is not None:
+            self._fail_admission(req, err)
+        elif not finite:
             self._finalize(req, "failed", FailureReason(
                 FailureReason.NONFINITE_LOGITS,
                 f"non-finite prefill logits at position {length - 1}"))
-            return True
-
-        self._activate(req, slot, length, pages)
-        tok = sample_token_row(row, self._key, slot, length - 1,
-                               temperature=self.temperature,
-                               top_k=self.top_k)
-        self.stats.prefill_s += time.perf_counter() - t0
-        self._emit(req, int(tok), row)
+        else:
+            self._emit(req, int(tok), row)
         return True
 
     # -- chunked prefill (docs/API.md §SLO scheduling) --------------------
@@ -949,90 +978,75 @@ class ServingEngine:
         chunk samples its first token. Returns False when paged
         backpressure parked it at the queue front (the ``_admit``
         contract)."""
-        t0 = time.perf_counter()
-        slot = None
+        slot, err = None, None
         held: List[int] = []
-        try:
-            if self._chaos is not None:
-                self._chaos.fire(chaos_mod.SITE_ALLOC, engine=self,
-                                 request=req)
-            slot = self._free.pop(0)
+        with self._admit_span(req):
+            try:
+                if self._chaos is not None:
+                    self._chaos.fire(chaos_mod.SITE_ALLOC, engine=self,
+                                     request=req)
+                slot = self._free.pop(0)
 
-            if self.kv_layout == "paged":
-                saved = self._saved_pages.pop(req.req_id, None)
-                if saved is not None:
-                    # preempt-resume page retention: instant, no prefill
-                    pages, resume_len = saved
-                    held = pages
+                if self.kv_layout == "paged":
+                    saved = self._saved_pages.pop(req.req_id, None)
+                    if saved is not None:
+                        # preempt-resume page retention: instant, no
+                        # prefill
+                        pages, resume_len = saved
+                        held = pages
+                        self.cache = self._restore_paged(
+                            self.cache, jnp.int32(slot),
+                            self._page_row(pages), jnp.int32(resume_len))
+                        self._activate(req, slot, resume_len, pages)
+                        req.prefill_pos = req.prefill_target = 0
+                        self._tokens[slot, 0] = req.tokens[-1]
+                        self._pos[slot] = resume_len
+                        self._remaining[slot] = \
+                            req.max_new_tokens - req.n_generated
+                        self.stats.page_resumes += 1
+                        return True
+
+                seq = req.prompt if not req.tokens else np.concatenate(
+                    [req.prompt, np.asarray(req.tokens, np.int32)])
+                length = int(seq.size)
+                start = 0
+                pages = None
+                if self.kv_layout == "paged":
+                    need = pages_needed(
+                        min(length + req.max_new_tokens, self.cache_len),
+                        self.kv_page_size)
+                    shared: List[int] = []
+                    if self._can_share and not req.tokens:
+                        shared = self._prefix_cache.match(seq,
+                                                          limit=length - 1)
+                        held = held + shared
+                    start = len(shared) * self.kv_page_size
+                    fresh = self._reserve_pages(need - len(shared))
+                    held = held + fresh
+                    pages = shared + fresh
+                    # install the page table up front: every chunk scatters
+                    # through it, and the pos map starts at the shared
+                    # prefix
                     self.cache = self._restore_paged(
                         self.cache, jnp.int32(slot), self._page_row(pages),
-                        jnp.int32(resume_len))
-                    self._activate(req, slot, resume_len, pages)
-                    req.prefill_pos = req.prefill_target = 0
-                    self._tokens[slot, 0] = req.tokens[-1]
-                    self._pos[slot] = resume_len
-                    self._remaining[slot] = \
-                        req.max_new_tokens - req.n_generated
-                    self.stats.page_resumes += 1
-                    self.stats.prefill_s += time.perf_counter() - t0
-                    return True
-
-            seq = req.prompt if not req.tokens else np.concatenate(
-                [req.prompt, np.asarray(req.tokens, np.int32)])
-            length = int(seq.size)
-            start = 0
-            pages = None
-            if self.kv_layout == "paged":
-                need = pages_needed(
-                    min(length + req.max_new_tokens, self.cache_len),
-                    self.kv_page_size)
-                shared: List[int] = []
-                if self._can_share and not req.tokens:
-                    shared = self._prefix_cache.match(seq, limit=length - 1)
-                    held = held + shared
-                start = len(shared) * self.kv_page_size
-                fresh = self._reserve_pages(need - len(shared))
-                held = held + fresh
-                pages = shared + fresh
-                # install the page table up front: every chunk scatters
-                # through it, and the pos map starts at the shared prefix
-                self.cache = self._restore_paged(
-                    self.cache, jnp.int32(slot), self._page_row(pages),
-                    jnp.int32(start))
-                if start > 0:
-                    self.stats.prefix_hit_tokens += start
-                elif self._can_share and not req.tokens:
-                    self._pending_publish.add(req.req_id)
-        except PagePoolExhausted as e:
-            if held:
-                self._pool.release(held)
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
-            if self._active:
-                req.status = "queued"
-                self._queue.appendleft(req)
-                log.info("parking request %d on page pressure (%s)",
-                         req.req_id, e)
-                return False
-            self._finalize(req, "failed", FailureReason(
-                FailureReason.KV_PAGES,
-                f"{e} with no active requests to drain"))
-            return True
-        except Exception as e:  # noqa: BLE001 -- isolate to this request
-            if held:
-                self._pool.release(held)
-            self._restore_slot(slot)
-            self.stats.prefill_s += time.perf_counter() - t0
-            log.warning("admission of request %d failed (%s: %s)",
-                        req.req_id, type(e).__name__, e)
-            self._finalize(req, "failed", FailureReason(
-                FailureReason.PREFILL_ERROR, f"{type(e).__name__}: {e}"))
-            return True
-
-        self._activate(req, slot, length, pages)
-        req.prefill_pos = start
-        req.prefill_target = length
-        self.stats.prefill_s += time.perf_counter() - t0
+                        jnp.int32(start))
+                    if start > 0:
+                        self.stats.prefix_hit_tokens += start
+                    elif self._can_share and not req.tokens:
+                        self._pending_publish.add(req.req_id)
+            except Exception as e:  # noqa: BLE001 -- isolate to this request
+                if held:
+                    self._pool.release(held)
+                self._restore_slot(slot)
+                err = e
+            else:
+                self._activate(req, slot, length, pages)
+                req.prefill_pos = start
+                req.prefill_target = length
+        if isinstance(err, PagePoolExhausted):
+            return self._park_or_fail(req, err)
+        if err is not None:
+            self._fail_admission(req, err)
         return True
 
     def _prefill_chunk(self, req: EngineRequest, budget: int) -> int:
@@ -1049,49 +1063,51 @@ class ServingEngine:
             return 0
         seq = req.prompt if not req.tokens else np.concatenate(
             [req.prompt, np.asarray(req.tokens, np.int32)])
-        used = 0
-        t0 = time.perf_counter()
-        try:
-            while req.prefill_pos < req.prefill_target:
-                start = req.prefill_pos
-                c = min(self._chunk_len, req.prefill_target - start)
-                if c > budget - used:
-                    break               # whole-chunk budget gating: defer
-                if self._chaos is not None:
-                    self._chaos.fire(chaos_mod.SITE_PREFILL_CHUNK,
-                                     engine=self, request=req,
-                                     start=start, size=c)
-                bucket = self._bucket(c)
-                toks = np.zeros((bucket,), np.int32)
-                toks[:c] = seq[start:start + c]
-                if self._watchdog is not None:
-                    self._watchdog.arm("prefill-chunk")
-                try:
-                    self.cache, logits = self._suffix_prefill(
-                        self.servable.params, self.cache,
-                        jnp.asarray(toks), jnp.int32(req.slot),
-                        jnp.int32(start), jnp.int32(c))
-                finally:
+        used, row, err = 0, None, None
+        with self._admit_span(req) as sp:
+            try:
+                while req.prefill_pos < req.prefill_target:
+                    start = req.prefill_pos
+                    c = min(self._chunk_len, req.prefill_target - start)
+                    if c > budget - used:
+                        break           # whole-chunk budget gating: defer
+                    if self._chaos is not None:
+                        self._chaos.fire(chaos_mod.SITE_PREFILL_CHUNK,
+                                         engine=self, request=req,
+                                         start=start, size=c)
+                    bucket = self._bucket(c)
+                    toks = np.zeros((bucket,), np.int32)
+                    toks[:c] = seq[start:start + c]
                     if self._watchdog is not None:
-                        self._watchdog.disarm()
-                        self.stats.watchdog_stalls = \
-                            len(self._watchdog.stalls)
-                req.prefill_pos += c
-                used += c
-                self.stats.prefilled_tokens += c
-                self.stats.prefill_chunks += 1
-                self.stats.bucket_hits[bucket] += 1
-            if req.prefill_pos < req.prefill_target:
-                return used                 # budget spent mid-prompt
-            row = np.asarray(logits[c - 1])
-        except Exception as e:  # noqa: BLE001 -- isolate to this request
+                        self._watchdog.arm("prefill-chunk")
+                    try:
+                        self.cache, logits = self._suffix_prefill(
+                            self.servable.params, self.cache,
+                            jnp.asarray(toks), jnp.int32(req.slot),
+                            jnp.int32(start), jnp.int32(c))
+                    finally:
+                        if self._watchdog is not None:
+                            self._watchdog.disarm()
+                            self.stats.watchdog_stalls = \
+                                len(self._watchdog.stalls)
+                    req.prefill_pos += c
+                    used += c
+                    self.stats.prefilled_tokens += c
+                    self.stats.prefill_chunks += 1
+                    self.stats.bucket_hits[bucket] += 1
+                if req.prefill_pos >= req.prefill_target:
+                    row = np.asarray(logits[c - 1])
+            except Exception as e:  # noqa: BLE001 -- isolate to this request
+                err = e
+            sp.set(tokens=used)
+        if err is not None:
             log.warning("chunked prefill of request %d failed (%s: %s)",
-                        req.req_id, type(e).__name__, e)
+                        req.req_id, type(err).__name__, err)
             self._finalize(req, "failed", FailureReason(
-                FailureReason.PREFILL_ERROR, f"{type(e).__name__}: {e}"))
+                FailureReason.PREFILL_ERROR, f"{type(err).__name__}: {err}"))
             return used
-        finally:
-            self.stats.prefill_s += time.perf_counter() - t0
+        if row is None:
+            return used                     # budget spent mid-prompt
 
         self.stats.prefills += 1
         if not np.all(np.isfinite(row)):
@@ -1461,30 +1477,38 @@ class ServingEngine:
         on host here). ``decoding`` is the slot set this window actually
         decodes -- mid-prefill slots are skipped at the drain (their rows
         are device no-ops and must not be quarantined or emitted)."""
-        t0 = time.perf_counter()
-        self.stats.steps += 1
-        self.stats.windows += 1
-        self.stats.occupancy_sum += len(decoding)
-        next_tok, ok, logits, self.cache = self._decode(
-            self.servable.params, self.cache, jnp.asarray(self._tokens),
-            jnp.asarray(self._pos), self._key, self.temperature, self.top_k)
-        toks = np.asarray(next_tok)             # (max_slots,) int32 only
-        ok_h = np.asarray(ok)                   # (max_slots,) bool
-        rows = np.asarray(logits[:, 0, :]) if self.collect_logits else None
-        self.stats.decode_s += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        for slot in decoding:
-            req = self._active[slot]
-            if not ok_h[slot]:
-                # non-finite logits: quarantine only this slot
-                self._finalize(req, "failed", FailureReason(
-                    FailureReason.NONFINITE_LOGITS,
-                    f"non-finite decode logits at position {req.pos}"))
-                continue
-            req.pos += 1
-            self._emit(req, int(toks[slot]),
-                       rows[slot] if rows is not None else None)
-        self.stats.sync_s += time.perf_counter() - t0
+        with self._window_span(1) as sp:
+            self.stats.steps += 1
+            self.stats.windows += 1
+            self.stats.occupancy_sum += len(decoding)
+            next_tok, ok, logits, self.cache = self._decode(
+                self.servable.params, self.cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._pos), self._key, self.temperature,
+                self.top_k)
+            toks = np.asarray(next_tok)         # (max_slots,) int32 only
+            ok_h = np.asarray(ok)               # (max_slots,) bool
+            rows = np.asarray(logits[:, 0, :]) if self.collect_logits \
+                else None
+            sp.set(tokens=int(ok_h[decoding].sum()))
+        with timed(self.stats, "sync_s", "repro.engine.sync"):
+            for slot in decoding:
+                req = self._active[slot]
+                if not ok_h[slot]:
+                    # non-finite logits: quarantine only this slot
+                    self._finalize(req, "failed", FailureReason(
+                        FailureReason.NONFINITE_LOGITS,
+                        f"non-finite decode logits at position {req.pos}"))
+                    continue
+                req.pos += 1
+                self._emit(req, int(toks[slot]),
+                           rows[slot] if rows is not None else None)
+
+    def _window_span(self, k: int):
+        """The ``repro.engine.window`` span of one decode window of ``k``
+        steps over ``max_slots`` rows, timed into ``stats.decode_s``;
+        ``tokens``, set at exit, the tokens it emitted."""
+        return timed(self.stats, "decode_s", "repro.engine.window", k=k,
+                     slots=self.max_slots, tokens=0)
 
     def _step_fused(self, k: int, decoding: List[int]) -> None:
         """The fused hot loop: K decode steps inside one jitted scan
@@ -1495,50 +1519,49 @@ class ServingEngine:
         ``max_new_tokens``; slots that hit EOS (or their budget, or
         non-finite logits) mid-window deactivate themselves on device and
         ride along as no-ops until the sync."""
-        t0 = time.perf_counter()
-        self.stats.steps += k
-        self.stats.windows += 1
-        toks, valid, state = self._decode_many(
-            self.servable.params, self.cache, jnp.asarray(self._tokens),
-            jnp.asarray(self._pos), jnp.asarray(self._remaining),
-            jnp.asarray(self._eos), self._key, k, self.temperature,
-            self.top_k)
-        self.cache = state["cache"]
-        toks_h = np.asarray(toks)               # (K, B) int32
-        valid_h = np.asarray(valid)             # (K, B) bool
-        failed_h = np.asarray(state["failed"])  # (B,) bool
-        # writable host mirrors (np.asarray of a jax array is read-only)
-        self._tokens = np.array(state["token"], np.int32)
-        self._pos = np.array(state["pos"], np.int32)
-        self._remaining = np.array(state["remaining"], np.int32)
-        self.stats.decode_s += time.perf_counter() - t0
+        with self._window_span(k) as sp:
+            self.stats.steps += k
+            self.stats.windows += 1
+            toks, valid, state = self._decode_many(
+                self.servable.params, self.cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._pos), jnp.asarray(self._remaining),
+                jnp.asarray(self._eos), self._key, k, self.temperature,
+                self.top_k)
+            self.cache = state["cache"]
+            toks_h = np.asarray(toks)               # (K, B) int32
+            valid_h = np.asarray(valid)             # (K, B) bool
+            failed_h = np.asarray(state["failed"])  # (B,) bool
+            # writable host mirrors (np.asarray of a jax array is read-only)
+            self._tokens = np.array(state["token"], np.int32)
+            self._pos = np.array(state["pos"], np.int32)
+            self._remaining = np.array(state["remaining"], np.int32)
+            emitted = int(valid_h.sum())
+            sp.set(tokens=emitted)
 
-        t0 = time.perf_counter()
-        self.stats.occupancy_sum += int(valid_h.sum())
-        window = decoding
-        for step in range(k):
-            for slot in window:
-                if not valid_h[step, slot]:
-                    continue
+        with timed(self.stats, "sync_s", "repro.engine.sync"):
+            self.stats.occupancy_sum += emitted
+            for step in range(k):
+                for slot in decoding:
+                    if not valid_h[step, slot]:
+                        continue
+                    req = self._active[slot]
+                    req.pos += 1
+                    tok = int(toks_h[step, slot])
+                    req.tokens.append(tok)
+                    self.stats.tokens_generated += 1
+                    if req.on_token is not None:
+                        req.on_token(req.req_id, tok)
+            for slot in decoding:
                 req = self._active[slot]
-                req.pos += 1
-                tok = int(toks_h[step, slot])
-                req.tokens.append(tok)
-                self.stats.tokens_generated += 1
-                if req.on_token is not None:
-                    req.on_token(req.req_id, tok)
-        for slot in window:
-            req = self._active[slot]
-            if failed_h[slot]:                  # device quarantined it
-                self._finalize(req, "failed", FailureReason(
-                    FailureReason.NONFINITE_LOGITS,
-                    f"non-finite decode logits in fused window at "
-                    f"position {req.pos}"))
-            elif self._pos[slot] < 0:           # device marked it finished
-                # _finalize re-zeroes the host mirrors; cache hygiene via
-                # free_slot as in the per-step path
-                self._finalize(req, "done")
-        self.stats.sync_s += time.perf_counter() - t0
+                if failed_h[slot]:              # device quarantined it
+                    self._finalize(req, "failed", FailureReason(
+                        FailureReason.NONFINITE_LOGITS,
+                        f"non-finite decode logits in fused window at "
+                        f"position {req.pos}"))
+                elif self._pos[slot] < 0:       # device marked it finished
+                    # _finalize re-zeroes the host mirrors; cache hygiene
+                    # via free_slot as in the per-step path
+                    self._finalize(req, "done")
 
     def run(self, max_steps: Optional[int] = None) -> List[EngineRequest]:
         """Drain the queue and all active slots; returns every request that

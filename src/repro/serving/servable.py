@@ -33,6 +33,7 @@ from repro.core.pruner import _path_name, oneshot_prune, tied_prune
 from repro.kernels.exec_plan import QuantPlan, RowPackPlan, ShardedPlan
 from repro.kernels.flash_decode import decode_kernel_override
 from repro.models import api as model_api
+from repro.runtime.trace import span
 from repro.serving.export import export_params
 from repro.serving.serialize import (LeafReader, ServableLoadError,
                                      build_like, config_from_dict,
@@ -186,12 +187,15 @@ class Servable:
 
         The callable is jit'd once per Servable with the packs held static;
         identical patterns across layers share one specialization (plans
-        hash by pattern fingerprint)."""
+        hash by pattern fingerprint). The span ``repro.servable.forward``
+        covers the dispatch only: the caller's wait for the logits stays
+        outside it."""
         if self._fwd_fn is None:
             cfg, packs = self.cfg, self.packs
             self._fwd_fn = jax.jit(
                 lambda p, b: model_api.model_forward(p, cfg, b, packs=packs))
-        logits, _aux = self._fwd_fn(self.params, self._as_batch(batch))
+        with span("repro.servable.forward"):
+            logits, _aux = self._fwd_fn(self.params, self._as_batch(batch))
         return logits
 
     def init_cache(self, batch_size: int, cache_len: int, frames=None,

@@ -6,12 +6,17 @@ serving path is compiled here for a *described* v5e chip -- no chip
 attached: the plan-consuming BSR matmul at BERT-base fused-QKV widths, its
 int8 variant with both scale granularities, and flash / paged flash decode
 at gemma3-4b attention widths. A passing compile is not a chip run; it only
-proves the compiler takes the kernel.
+proves the compiler takes the kernel. The compiled text also pins the
+kernels' own names, which the benchmark's rooflines look for in the trace.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every test
 worker imports this file.
 """
+import ast
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -23,6 +28,8 @@ from repro.kernels.bsr_matmul import pack_bsr, plan_dds, plan_dds_q
 from repro.kernels.exec_plan import (build_plan, pack_plan_data,
                                      plan_kernel_sequence, quant_granularity)
 from repro.kernels.flash_decode import flash_decode, paged_flash_decode
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -66,15 +73,20 @@ def _qkv_plan(tile=(128, 128), density=0.3):
     return plan, pack_plan_data(plan, pk.data).shape
 
 
-@pytest.mark.parametrize("m", [384, 8])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_plan_dds_compiles(one_chip, dtype, m):
+def _plan_dds(one_chip, dtype=jnp.bfloat16, m=384):
     plan, vshape = _qkv_plan()
     seq = plan_kernel_sequence(plan)
     x = jax.ShapeDtypeStruct((m, plan.shape[1]), dtype, sharding=one_chip)
     d = jax.ShapeDtypeStruct(vshape, dtype, sharding=one_chip)
-    _compile(lambda x, d: plan_dds(x, d, seq, n=plan.shape[0],
-                                   tile=plan.tile, interpret=False), x, d)
+    return _compile(lambda x, d: plan_dds(x, d, seq, n=plan.shape[0],
+                                          tile=plan.tile, interpret=False),
+                    x, d)
+
+
+@pytest.mark.parametrize("m", [384, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_plan_dds_compiles(one_chip, dtype, m):
+    _plan_dds(one_chip, dtype, m)
 
 
 @pytest.mark.parametrize("granularity", ["block", "row"])
@@ -105,13 +117,51 @@ def test_flash_decode_compiles(one_chip, hkv, g, d):
         s((b, t), jnp.int32), s((b,), jnp.int32))
 
 
-def test_paged_flash_decode_compiles(one_chip):
+def _paged_flash_decode(one_chip):
     b, hkv, g, d, ps, npg, dt = 4, 4, 2, 256, 16, 64, jnp.bfloat16
     n_pages = b * npg
     s = lambda shape, dtype=dt: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=one_chip)
-    _compile(lambda q, kp, vp, pt, pm, pos: paged_flash_decode(
+    return _compile(lambda q, kp, vp, pt, pm, pos: paged_flash_decode(
         q, kp, vp, pt, pm, pos, interpret=False),
         s((b, 1, hkv * g, d)), s((n_pages, ps, hkv, d)),
         s((n_pages, ps, hkv, d)), s((b, npg), jnp.int32),
         s((b, npg * ps), jnp.int32), s((b,), jnp.int32))
+
+
+def test_paged_flash_decode_compiles(one_chip):
+    _paged_flash_decode(one_chip)
+
+
+def _needles(family):
+    """``KERNELS`` of ``bench/families/<family>.py``: per roofline, the
+    string it looks for in a device op's own name."""
+    with open(os.path.join(ROOT, "bench", "families", family + ".py")) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and any(
+                getattr(t, "id", None) == "KERNELS" for t in node.targets):
+            return ast.literal_eval(node.value)
+    raise AssertionError(f"bench/families/{family}.py has no KERNELS")
+
+
+def _custom_call_names(compiled):
+    """Own names of the compiled custom calls, numbering dropped:
+    '%_plan_dds_call.1 = bf16[...] custom-call(...)' -> '_plan_dds_call'."""
+    return {re.sub(r"\.\d+$", "", m) for m in re.findall(
+        r"%([\w.-]+) = [^\n]*custom-call\(", compiled.as_text())}
+
+
+@pytest.mark.parametrize("family,kernel,compile_fn", [
+    ("bert", "sparse_matmul", _plan_dds),
+    ("lm", "sparse_matmul", _plan_dds),
+    ("lm", "decode_attn", _paged_flash_decode),
+])
+def test_kernel_names_carry_the_benchmark_needles(one_chip, family, kernel,
+                                                  compile_fn):
+    """A rename of ``_plan_dds_call`` or ``_paged_flash_call`` would leave
+    the rooflines nothing to read: the benchmark finds each kernel by a
+    string its op name contains."""
+    needle = _needles(family)[kernel]
+    names = _custom_call_names(compile_fn(one_chip))
+    assert any(needle in n for n in names), (needle, names)
