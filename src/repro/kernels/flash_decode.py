@@ -17,7 +17,10 @@ run the flash-style online softmax on chip instead:
       BlockSpec index map, so each grid step DMAs its page from the pool
       *in place*. This kills the dense-view reassembly tax: the PR-7 paged
       decode gathered a (B, T, Hkv, D) contiguous view per step per layer
-      before attending; here no view is ever materialized.
+      before attending; here no view is ever materialized. The pools are
+      stored (.., page_size, Hkv*D), the layout the blocks are cut from,
+      and the layer scan passes them stacked with the layer index, so
+      nothing copies or relayouts a pool on the way in.
 
 Masking follows decode_attention exactly: ``pos_map`` holds the absolute
 position stored in each cache slot (-1 = empty), queries see positions
@@ -118,7 +121,8 @@ def _flash_decode_kernel(*refs, n_prefetch, num_split, window, scale):
 
     refs = (*scalar_prefetch, q, k, v, kvp, o, m_scratch, l_scratch, acc).
     prefetch[0] is the per-slot position vector; the paged variant adds the
-    flattened page table (consumed only by the BlockSpec index maps). k/v
+    flattened page table and the layer index (consumed only by the BlockSpec
+    index maps). k/v
     blocks are one KV head's (1, ck, D) slice of the (.., ck, Hkv*D) cache
     view, kvp one split's (1, 1, ck) row of positions.
     """
@@ -238,59 +242,64 @@ def flash_decode(q, k_cache, v_cache, kv_positions, pos, *, window=0,
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("window", "npg", "interpret"))
-def _paged_flash_call(q4, k_pages, v_pages, pt_flat, pm, pos, *, window, npg,
-                      interpret):
+def _paged_flash_call(q4, k_pages, v_pages, pt_flat, pm, pos, layer, *,
+                      window, npg, interpret):
     b, hkv, g, d = q4.shape
-    n_pages, ps = k_pages.shape[:2]
+    ps = k_pages.shape[2]
     grid = (b, hkv, npg)
-    # same free reshapes as _flash_decode_call: pools -> (pages, ps, Hkv*D),
-    # pos_map -> (B*NP, 1, ps) so one page is one (1, 1, ps) block
-    k_pages = k_pages.reshape(n_pages, ps, hkv * d)
-    v_pages = v_pages.reshape(n_pages, ps, hkv * d)
+    # the stacked pools (P, pages, ps, Hkv*D) are read where they lie: one
+    # block is one layer's page of one KV head; pos_map -> (B*NP, 1, ps)
+    # (a free reshape) so one page is one (1, 1, ps) block
     pm = pm.reshape(b * npg, 1, ps)
 
-    def page_map(b, h, s, pos, pt):
+    def page_map(b, h, s, pos, pt, layer):
         # unmapped (-1) pages clip to page 0; their pos_map slots are -1 so
         # every lane of the split is masked before the exp
-        return (jnp.maximum(pt[b * npg + s], 0), 0, h)
+        return (layer[0], jnp.maximum(pt[b * npg + s], 0), 0, h)
 
     return pl.pallas_call(
-        functools.partial(_flash_decode_kernel, n_prefetch=2, num_split=npg,
+        functools.partial(_flash_decode_kernel, n_prefetch=3, num_split=npg,
                           window=window, scale=d ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d),
-                             lambda b, h, s, pos, pt: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, d), page_map),
-                pl.BlockSpec((1, ps, d), page_map),
+                             lambda b, h, s, pos, pt, layer: (b, h, 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, ps, d), page_map),
+                pl.BlockSpec((pl.squeezed, 1, ps, d), page_map),
                 pl.BlockSpec((1, 1, ps),
-                             lambda b, h, s, pos, pt: (b * npg + s, 0, 0)),
+                             lambda b, h, s, pos, pt, layer:
+                             (b * npg + s, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
-                                   lambda b, h, s, pos, pt: (b, h, 0, 0)),
+                                   lambda b, h, s, pos, pt, layer:
+                                   (b, h, 0, 0)),
             scratch_shapes=[pltpu.VMEM((g, 128), jnp.float32),
                             pltpu.VMEM((g, 128), jnp.float32),
                             pltpu.VMEM((g, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q4.dtype),
         interpret=interpret,
-    )(pos, pt_flat, q4, k_pages, v_pages, pm)
+    )(pos, pt_flat, layer, q4, k_pages, v_pages, pm)
 
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, pos_map, pos, *,
-                       window=0, interpret=None):
+                       window=0, layer=None, interpret=None):
     """One-step decode straight off the page pools.
 
-    q (B,1,Hq,D); pools (n_pages, page_size, Hkv, D); ``page_table``
+    q (B,1,Hq,D); pools (n_pages, page_size, Hkv*D), or stacked
+    (P, n_pages, page_size, Hkv*D) and read at layer ``layer`` (a traced
+    scalar: the layer scan's carried pools are never sliced); ``page_table``
     (B, NP) physical page per logical page (-1 = unmapped); ``pos_map``
     (B, NP*page_size) per-slot occupancy as in the dense layout. Each grid
     step DMAs one page via the prefetched table -- the per-step dense-view
     gather of the XLA paged path never happens.
     """
+    if layer is None:                   # one layer's pool: a stack of one
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, _, hq, d = q.shape
-    _, ps, hkv, _ = k_pages.shape
+    ps, hkv = k_pages.shape[2], k_pages.shape[3] // d
     npg = page_table.shape[1]
     g = hq // hkv
     assert pos_map.shape == (b, npg * ps), (pos_map.shape, npg, ps)
@@ -300,5 +309,6 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, pos_map, pos, *,
     out = _paged_flash_call(q[:, 0].reshape(b, hkv, g, d), k_pages, v_pages,
                             page_table.reshape(-1).astype(jnp.int32),
                             jnp.asarray(pos_map, jnp.int32), posv,
+                            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
                             window=window, npg=npg, interpret=interpret)
     return out.reshape(b, 1, hq, d)

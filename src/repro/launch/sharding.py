@@ -121,7 +121,7 @@ _CACHE_MODEL_DIM = {
 #: the kv-head / latent-rank dim goes over "model", mirroring the dense
 #: rules above. page_table / pos_map stay replicated int32 bookkeeping.
 _PAGE_POOL_MODEL_DIM = {
-    "k_pages": 2, "v_pages": 2,      # (N, ps, Hkv, D) -> kv heads
+    "k_pages": 2, "v_pages": 2,      # (N, ps, Hkv*D), heads-major -> kv heads
     "c_kv_pages": 2,                 # (N, ps, r) -> latent rank
     "k_rope_pages": None,            # (N, ps, dr) shared rope key: replicated
 }

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.kernels.flash_decode import (flash_decode, paged_flash_decode,
                                         resolved_decode_kernel)
 from repro.models.common import (apply_rope, init_linear, linear, normal_init,
-                                 paged_bulk_write, paged_row_write, paged_view)
+                                 paged_row_write, paged_view)
 
 NEG_INF = -1e30
 
@@ -406,7 +406,9 @@ def init_cache_attn(cfg, batch, cache_len, window=0, dtype=None, paged=None):
     (dequantized tile-wise inside attention).
 
     ``paged`` (a ``models.common.PagedLayout``) switches GLOBAL layers to
-    the pooled layout: K/V live in (n_pages, page_size, Hkv, D) page pools
+    the pooled layout: K/V live in (n_pages, page_size, Hkv*D) page pools
+    -- heads-major rows, the layout paged_flash_decode cuts its blocks
+    from, so no step relayouts a pool --
     addressed through a per-slot ``page_table`` (batch, T // page_size) of
     physical page ids (-1 = unmapped); ``pos_map`` keeps its dense (batch,
     T) form, so the decode masking -- and therefore the attention math --
@@ -421,8 +423,8 @@ def init_cache_attn(cfg, batch, cache_len, window=0, dtype=None, paged=None):
                 "kv_layout='paged' does not compose with kv_cache_quant yet"
                 " (int8 page pools + per-page scales are future work)")
         npg = paged.table_width(cache_len)
-        pshape = (paged.n_pages, paged.page_size, cfg.n_kv_heads,
-                  cfg.head_dim)
+        pshape = (paged.n_pages, paged.page_size,
+                  cfg.n_kv_heads * cfg.head_dim)
         return {"k_pages": jnp.zeros(pshape, dtype),
                 "v_pages": jnp.zeros(pshape, dtype),
                 "page_table": jnp.full((batch, npg), -1, jnp.int32),
@@ -477,7 +479,8 @@ def _write_prefill_kv(cache, k, v, length):
 
 def apply_attention(p, x, cfg, *, positions, window=0, cache=None, pos=None,
                     packs=None, causal=True, kv_override=None,
-                    prefill_len=None, page_slot=None, page_start=None):
+                    prefill_len=None, page_slot=None, page_start=None,
+                    layer=None):
     """x: (B,S,d). Returns (out, new_cache). Train/prefill when cache is None.
     With a cache and S > 1, the call is a *prompt prefill*: normal causal
     attention over the S tokens plus a bulk cache write of positions
@@ -498,6 +501,8 @@ def apply_attention(p, x, cfg, *, positions, window=0, cache=None, pos=None,
         prefill on a paged layout is not defined -- the engine prefills
         into a dense batch-1 sub-cache and page-scatters it instead
         (lm.write_slot_paged).
+    ``layer`` set means the pools are the layer scan's stacked (P, ...)
+    carry and this layer's is ``layer`` (models/lm.py).
 
     A DENSE cache with ``page_slot``/``page_start`` and S > 1 is the same
     suffix/chunk-prefill contract on slot-dense storage (chunked prefill,
@@ -555,23 +560,26 @@ def apply_attention(p, x, cfg, *, positions, window=0, cache=None, pos=None,
                 "with write_slot_paged, or pass page_slot/page_start for a "
                 "shared-prefix suffix prefill")
         assert kv_override is None and b == 1
-        n, ps = cache["k_pages"].shape[0], cache["k_pages"].shape[1]
         npg = cache["page_table"].shape[1]
         length = s if prefill_len is None else prefill_len
         start = jnp.asarray(page_start, jnp.int32)
         pt_row = cache["page_table"][page_slot]                  # (NP,)
         pos_i = start + jnp.arange(s)
-        validw = jnp.arange(s) < length
-        pp = pt_row[jnp.clip(pos_i // ps, 0, npg - 1)]
-        pp = jnp.where(validw & (pp >= 0), pp, n)                # OOB: drop
-        kp = cache["k_pages"].at[pp, pos_i % ps].set(k[0])
-        vp = cache["v_pages"].at[pp, pos_i % ps].set(v[0])
-        pm_row = paged_suffix_positions(npg * ps, start, length)
+        validw = jnp.arange(s) < length                          # else drop
+        pts = jnp.broadcast_to(pt_row, (s, npg))     # one table row per token
+        kp = paged_row_write(cache["k_pages"], pts, pos_i, k[0], validw,
+                             layer)
+        vp = paged_row_write(cache["v_pages"], pts, pos_i, v[0], validw,
+                             layer)
+        pm_row = paged_suffix_positions(cache["pos_map"].shape[1], start,
+                                        length)
         pm = cache["pos_map"].at[page_slot].set(pm_row)
         new_cache = {"k_pages": kp, "v_pages": vp, "pos_map": pm,
                      "page_table": cache["page_table"]}
-        k_view = paged_view(kp, pt_row[None], pm_row[None])      # (1,T,H,D)
-        v_view = paged_view(vp, pt_row[None], pm_row[None])
+        k_view = paged_view(kp, pt_row[None], pm_row[None], layer
+                            ).reshape(k.shape[0], -1, *k.shape[2:])
+        v_view = paged_view(vp, pt_row[None], pm_row[None], layer
+                            ).reshape(v.shape[0], -1, *v.shape[2:])
         qpos = pos_i[None, :, None]                              # (1,S,1)
         ok = (pm_row[None, None, :] >= 0) & (pm_row[None, None, :] <= qpos)
         out = masked_attention(q, k_view, v_view, ok)
@@ -653,21 +661,25 @@ def apply_attention(p, x, cfg, *, positions, window=0, cache=None, pos=None,
         posv = as_slot_positions(pos, b)
         active = posv >= 0
         pt = cache["page_table"]
-        kp = paged_row_write(cache["k_pages"], pt, posv, k[:, 0], active)
-        vp = paged_row_write(cache["v_pages"], pt, posv, v[:, 0], active)
+        kp = paged_row_write(cache["k_pages"], pt, posv, k[:, 0], active,
+                             layer)
+        vp = paged_row_write(cache["v_pages"], pt, posv, v[:, 0], active,
+                             layer)
         pm = _masked_row_write(cache["pos_map"], jnp.arange(b),
                                jnp.maximum(posv, 0), jnp.maximum(posv, 0),
                                active)
         new_cache = {"k_pages": kp, "v_pages": vp, "pos_map": pm,
                      "page_table": pt}
         if resolved_decode_kernel(cfg.n_kv_heads, hd,
-                                  kp.shape[1]) == "flash":
+                                  pm.shape[1] // pt.shape[1]) == "flash":
             # the split-K kernel reads pages in place via the prefetched
             # table -- no per-step dense-view gather
-            out = paged_flash_decode(q, kp, vp, pt, pm, posv, window=window)
+            out = paged_flash_decode(q, kp, vp, pt, pm, posv, window=window,
+                                     layer=layer)
         else:
-            k_view = paged_view(kp, pt, pm)
-            v_view = paged_view(vp, pt, pm)
+            kv_shape = (b, pm.shape[1], cfg.n_kv_heads, hd)
+            k_view = paged_view(kp, pt, pm, layer).reshape(kv_shape)
+            v_view = paged_view(vp, pt, pm, layer).reshape(kv_shape)
             out = decode_attention(q, k_view, v_view, pm, posv,
                                    window=window)
     else:

@@ -195,6 +195,10 @@ def prefill_conv_history(x, valid, length, width, dtype):
 # never reach a device gather/scatter directly (JAX wraps negative indices);
 # gathers clip into range and mask by pos_map, scatters map invalid rows to
 # n_pages (out of bounds HIGH), which jit scatter semantics DROP.
+#
+# The scan-stacked layers keep their pools stacked (P, n_pages, ...) in the
+# layer scan's carry (models/lm.py); ``layer`` then names the layer whose
+# pool an op reads or writes, so no layer's pool is ever sliced out.
 
 import dataclasses as _dataclasses
 
@@ -221,37 +225,47 @@ class PagedLayout:
         return cache_len // self.page_size
 
 
-def paged_view(pages, page_table, pos_map):
+def _pool_lead(layer):
+    """Index prefix of a pool: () for one layer's (N, ps, ...) pool,
+    (layer,) for a stacked (P, N, ps, ...) one."""
+    return () if layer is None else (layer,)
+
+
+def paged_view(pages, page_table, pos_map, layer=None):
     """Gather a slot-contiguous (B, T, ...) view out of a page pool.
 
-    ``pages``: (N, ps, ...); ``page_table``: (B, NP) int32 page ids (-1 =
-    unmapped); ``pos_map``: (B, T = NP*ps) absolute positions (-1 = empty).
-    Unmapped/unwritten positions read as EXACT zeros -- the view is then
-    elementwise identical to the dense slot cache the same writes would
-    have produced (dense caches zero-init and zero-reset), which is what
-    makes the paged decode path bit-exact against the dense oracle."""
+    ``pages``: (N, ps, ...), or (P, N, ps, ...) read at ``layer``;
+    ``page_table``: (B, NP) int32 page ids (-1 = unmapped); ``pos_map``:
+    (B, T = NP*ps) absolute positions (-1 = empty). Unmapped/unwritten
+    positions read as EXACT zeros -- the view is then elementwise identical
+    to the dense slot cache the same writes would have produced (dense
+    caches zero-init and zero-reset), which is what makes the paged decode
+    path bit-exact against the dense oracle."""
+    lead = _pool_lead(layer)
     b, npg = page_table.shape
-    n, ps = pages.shape[0], pages.shape[1]
+    n, ps, *row = pages.shape[len(lead):]
     safe = jnp.clip(page_table, 0, n - 1)            # gather: clip, mask below
-    view = pages[safe]                               # (B, NP, ps, ...)
-    view = view.reshape((b, npg * ps) + pages.shape[2:])
-    keep = (pos_map >= 0).reshape((b, npg * ps) + (1,) * (pages.ndim - 2))
+    view = pages[lead + (safe,)]                     # (B, NP, ps, ...)
+    view = view.reshape((b, npg * ps, *row))
+    keep = (pos_map >= 0).reshape((b, npg * ps) + (1,) * len(row))
     return jnp.where(keep, view, jnp.zeros_like(view))
 
 
-def paged_row_write(pages, page_table, positions, val, active):
+def paged_row_write(pages, page_table, positions, val, active, layer=None):
     """Scatter one new position per batch row into the pool: row ``i``'s
     value lands in page ``page_table[i, positions[i] // ps]`` at offset
-    ``positions[i] % ps``. Inactive/unmapped rows are redirected to page id
-    ``n_pages`` -- out of bounds, so the jit scatter DROPS them (the paged
-    analogue of attention._masked_row_write)."""
-    n, ps = pages.shape[0], pages.shape[1]
+    ``positions[i] % ps`` (of layer ``layer``'s pool when ``pages`` is
+    stacked); ``val[i]`` is reshaped to the pool's row. Inactive/unmapped
+    rows are redirected to page id ``n_pages`` -- out of bounds, so the jit
+    scatter DROPS them (the paged analogue of attention._masked_row_write)."""
+    lead = _pool_lead(layer)
+    n, ps, *row = pages.shape[len(lead):]
     npg = page_table.shape[1]
     posv = jnp.maximum(positions, 0)
     rows = jnp.arange(page_table.shape[0])
     pp = page_table[rows, jnp.clip(posv // ps, 0, npg - 1)]
     pp = jnp.where(active & (pp >= 0), pp, n)        # OOB-high => dropped
-    return pages.at[pp, posv % ps].set(val)
+    return pages.at[lead + (pp, posv % ps)].set(val.reshape(len(rows), *row))
 
 
 def paged_bulk_write(pages, page_row, vals):

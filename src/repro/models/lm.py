@@ -87,7 +87,7 @@ def init_lm(key, cfg: ModelConfig):
 
 def _apply_layer(p, h, cfg, kind: LayerKind, *, positions, cache=None,
                  pos=None, packs=None, prefill_len=None, page_slot=None,
-                 page_start=None):
+                 page_start=None, layer=None):
     hn = apply_norm(p["norm1"], h, cfg.norm)
     aux = jnp.zeros((), jnp.float32)
     mix_packs = _layer_packs(packs, "attn") or _layer_packs(packs, "mixer")
@@ -96,13 +96,13 @@ def _apply_layer(p, h, cfg, kind: LayerKind, *, positions, cache=None,
             p["attn"], hn, cfg, positions=positions, window=kind.window,
             cache=cache.get("mix") if cache else None, pos=pos,
             packs=mix_packs, prefill_len=prefill_len, page_slot=page_slot,
-            page_start=page_start)
+            page_start=page_start, layer=layer)
     elif kind.mixer == "mla":
         out, new_mix_cache = mla_mod.apply_mla(
             p["attn"], hn, cfg, positions=positions,
             cache=cache.get("mix") if cache else None, pos=pos,
             packs=mix_packs, prefill_len=prefill_len, page_slot=page_slot,
-            page_start=page_start)
+            page_start=page_start, layer=layer)
     elif kind.mixer == "ssm":
         out, new_mix_cache = ssm_mod.apply_ssm(
             p["mixer"], hn, cfg, cache=cache.get("mix") if cache else None,
@@ -259,6 +259,48 @@ def _is_pool_leaf(path):
     return isinstance(name, str) and name.endswith("_pages")
 
 
+def _split_pools(c):
+    """One layer's cache -> (its ``*_pages`` pool leaves, the rest)."""
+    pools = {k: v for k, v in c["mix"].items() if k.endswith("_pages")}
+    return pools, {"mix": {k: v for k, v in c["mix"].items()
+                           if k not in pools}}
+
+
+def _join_pools(rest, pools):
+    return {"mix": {**rest["mix"], **pools}}
+
+
+def _scan_blocks(params, cache, cfg: ModelConfig, h, packs, **layer_kw):
+    """Run the scan-stacked ``blocks`` layers over ``h`` with their caches
+    -> (h, new block caches). Page pools ride in the scan carry, stacked
+    (P, n_pages, ...), and each layer reads and writes its own through the
+    layer index: scanned as ``xs``/``ys`` instead, every layer of every
+    step would slice its pool out and stack it back, whole-pool copies
+    where one row per slot changes. Everything else (page tables, pos
+    maps, rings, recurrent state) is small and scans as ``xs``/``ys``."""
+    _, pattern, n_periods, _ = cfg.layer_plan()
+    pools, rest = zip(*map(_split_pools, cache["blocks"]))
+
+    def body(carry, xs):
+        h, pools = carry
+        layer_ps, layer_cs, l = xs
+        new_pools, new_cs = [], []
+        for i, kind in enumerate(pattern):
+            h, c, _ = _apply_layer(layer_ps[i], h, cfg, kind,
+                                   cache=_join_pools(layer_cs[i], pools[i]),
+                                   layer=l,
+                                   packs=_layer_packs(packs, f"blocks/{i}"),
+                                   **layer_kw)
+            p, r = _split_pools(c)
+            new_pools.append(p)
+            new_cs.append(r)
+        return (h, tuple(new_pools)), tuple(new_cs)
+
+    (h, pools), rest = jax.lax.scan(
+        body, (h, pools), (params["blocks"], rest, jnp.arange(n_periods)))
+    return h, tuple(map(_join_pools, rest, pools))
+
+
 def reset_slot(cache, slot):
     """Zero request slot ``slot``: attention KV + pos_map AND the SSM/RgLRU
     recurrent and conv state, so a recycled slot cannot leak its previous
@@ -379,7 +421,7 @@ def decode_step(params, cache, cfg: ModelConfig, token, pos, *, packs=None):
     and cache write slot. Rows with ``pos < 0`` are inactive -- their cache
     state is left untouched and their logits are meaningless.
     """
-    prefix, pattern, n_periods, suffix = cfg.layer_plan()
+    prefix, _, n_periods, suffix = cfg.layer_plan()
     b = token.shape[0]
     h = jnp.take(params["embed"]["w"], token, axis=0)
     if cfg.scale_embedding:
@@ -396,18 +438,8 @@ def decode_step(params, cache, cfg: ModelConfig, token, pos, *, packs=None):
 
     new_blocks = cache["blocks"]
     if n_periods > 0:
-        def body(h, xs):
-            layer_ps, layer_cs = xs
-            new_cs = []
-            for i, kind in enumerate(pattern):
-                h, c, _ = _apply_layer(layer_ps[i], h, cfg, kind,
-                                       positions=positions, cache=layer_cs[i],
-                                       pos=pos,
-                                       packs=_layer_packs(packs, f"blocks/{i}"))
-                new_cs.append(c)
-            return h, tuple(new_cs)
-        h, new_blocks = jax.lax.scan(body, h,
-                                     (params["blocks"], cache["blocks"]))
+        h, new_blocks = _scan_blocks(params, cache, cfg, h, packs,
+                                     positions=positions, pos=pos)
 
     new_suffix = []
     for i, kind in enumerate(suffix):
@@ -437,7 +469,7 @@ def prefill_suffix(params, cache, cfg: ModelConfig, tokens, slot, start,
     capable mixer: global/windowed attention (dense rings + paged pools),
     MLA latents, SSM state carry, RG-LRU state carry. Sample the next
     token from ``logits[0, length - 1]`` after the final chunk."""
-    prefix, pattern, n_periods, suffix = cfg.layer_plan()
+    prefix, _, n_periods, suffix = cfg.layer_plan()
     b, s = tokens.shape
     length = s if length is None else length
     h = jnp.take(params["embed"]["w"], tokens, axis=0)
@@ -457,19 +489,9 @@ def prefill_suffix(params, cache, cfg: ModelConfig, tokens, slot, start,
 
     new_blocks = cache["blocks"]
     if n_periods > 0:
-        def body(h, xs):
-            layer_ps, layer_cs = xs
-            new_cs = []
-            for i, kind in enumerate(pattern):
-                h, c, _ = _apply_layer(layer_ps[i], h, cfg, kind,
-                                       positions=positions, cache=layer_cs[i],
-                                       prefill_len=length, page_slot=slot,
-                                       page_start=start,
-                                       packs=_layer_packs(packs, f"blocks/{i}"))
-                new_cs.append(c)
-            return h, tuple(new_cs)
-        h, new_blocks = jax.lax.scan(body, h,
-                                     (params["blocks"], cache["blocks"]))
+        h, new_blocks = _scan_blocks(params, cache, cfg, h, packs,
+                                     positions=positions, prefill_len=length,
+                                     page_slot=slot, page_start=start)
 
     new_suffix = []
     for i, kind in enumerate(suffix):
@@ -499,7 +521,7 @@ def prefill_cache(params, cache, cfg: ModelConfig, tokens, length=None, *,
     trace. Returns (logits (B, S, V) f32, cache) -- sample the next token
     from ``logits[:, length - 1]``.
     """
-    prefix, pattern, n_periods, suffix = cfg.layer_plan()
+    prefix, _, n_periods, suffix = cfg.layer_plan()
     b, s = tokens.shape
     length = s if length is None else length
     h = jnp.take(params["embed"]["w"], tokens, axis=0)
@@ -517,18 +539,8 @@ def prefill_cache(params, cache, cfg: ModelConfig, tokens, length=None, *,
 
     new_blocks = cache["blocks"]
     if n_periods > 0:
-        def body(h, xs):
-            layer_ps, layer_cs = xs
-            new_cs = []
-            for i, kind in enumerate(pattern):
-                h, c, _ = _apply_layer(layer_ps[i], h, cfg, kind,
-                                       positions=positions, cache=layer_cs[i],
-                                       prefill_len=length,
-                                       packs=_layer_packs(packs, f"blocks/{i}"))
-                new_cs.append(c)
-            return h, tuple(new_cs)
-        h, new_blocks = jax.lax.scan(body, h,
-                                     (params["blocks"], cache["blocks"]))
+        h, new_blocks = _scan_blocks(params, cache, cfg, h, packs,
+                                     positions=positions, prefill_len=length)
 
     new_suffix = []
     for i, kind in enumerate(suffix):

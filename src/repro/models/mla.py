@@ -66,7 +66,9 @@ def _project_q(p, x, cfg, packs=None):
 
 
 def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
-              prefill_len=None, page_slot=None, page_start=None):
+              prefill_len=None, page_slot=None, page_start=None, layer=None):
+    """``layer`` set: the latent pools are the layer scan's stacked carry,
+    this layer's at ``layer`` (attention.apply_attention)."""
     b, s, d = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     q_nope, q_rope = _project_q(p, x, cfg, packs)
@@ -89,22 +91,23 @@ def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
         pos_i = start + jnp.arange(s)
         validw = jnp.arange(s) < length
         if "c_kv_pages" in cache:
-            n, psz = (cache["c_kv_pages"].shape[0],
-                      cache["c_kv_pages"].shape[1])
             npg = cache["page_table"].shape[1]
             pt_row = cache["page_table"][page_slot]              # (NP,)
-            pp = pt_row[jnp.clip(pos_i // psz, 0, npg - 1)]
-            pp = jnp.where(validw & (pp >= 0), pp, n)            # OOB: drop
-            cp = cache["c_kv_pages"].at[pp, pos_i % psz].set(c_kv[0])
-            rp = cache["k_rope_pages"].at[pp, pos_i % psz].set(
-                k_rope[0, :, 0, :])
-            pm_row = paged_suffix_positions(npg * psz, start, length)
+            pts = jnp.broadcast_to(pt_row, (s, npg))  # a table row per token
+            cp = paged_row_write(cache["c_kv_pages"], pts, pos_i, c_kv[0],
+                                 validw, layer)
+            rp = paged_row_write(cache["k_rope_pages"], pts, pos_i,
+                                 k_rope[0, :, 0, :], validw, layer)
+            pm_row = paged_suffix_positions(cache["pos_map"].shape[1], start,
+                                            length)
             new_cache = {"c_kv_pages": cp, "k_rope_pages": rp,
                          "pos_map": cache["pos_map"].at[page_slot].set(
                              pm_row),
                          "page_table": cache["page_table"]}
-            c_view = paged_view(cp, pt_row[None], pm_row[None])  # (1,T,r)
-            r_view = paged_view(rp, pt_row[None], pm_row[None])  # (1,T,dr)
+            c_view = paged_view(cp, pt_row[None], pm_row[None],
+                                layer)                          # (1,T,r)
+            r_view = paged_view(rp, pt_row[None], pm_row[None],
+                                layer)                          # (1,T,dr)
         else:
             t = cache["c_kv"].shape[1]
             nslots = cache["c_kv"].shape[0]
@@ -183,14 +186,14 @@ def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
         # identical to the dense latent cache, so decode stays bit-exact
         pt = cache["page_table"]
         cp = paged_row_write(cache["c_kv_pages"], pt, posv, c_kv[:, 0],
-                             active)
+                             active, layer)
         rp = paged_row_write(cache["k_rope_pages"], pt, posv,
-                             k_rope[:, 0, 0, :], active)
+                             k_rope[:, 0, 0, :], active, layer)
         pm = _masked_row_write(cache["pos_map"], rows,
                                jnp.maximum(posv, 0), jnp.maximum(posv, 0),
                                active)
-        c_cache = paged_view(cp, pt, pm)
-        r_cache = paged_view(rp, pt, pm)
+        c_cache = paged_view(cp, pt, pm, layer)
+        r_cache = paged_view(rp, pt, pm, layer)
         new_cache = {"c_kv_pages": cp, "k_rope_pages": rp, "pos_map": pm,
                      "page_table": pt}
     else:

@@ -50,17 +50,21 @@ def _mla_cfg():
         pattern=(LayerKind("mla", "dense"),), dtype="float32")
 
 
-def _windowed_cfg():
+def _windowed_cfg(n_layers=2):
     """Mixed local+global attention: windowed layers stay slot-dense,
     global layers page -- the partially-paged cache tree."""
     return ModelConfig(
-        arch="paged-window-smoke", family="dense", n_layers=2, d_model=64,
-        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=512,
+        arch="paged-window-smoke", family="dense", n_layers=n_layers,
+        d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+        vocab_size=512,
         pattern=(LayerKind("attn", "dense", window=16),
                  LayerKind("attn", "dense")), dtype="float32")
 
 
-CFGS = {"attn": _attn_cfg, "mla": _mla_cfg, "windowed": _windowed_cfg}
+# attn and mla scan two periods of one layer; "windowed2" two periods of
+# the mixed pair, so a paged layer reading another period's pool fails
+CFGS = {"attn": _attn_cfg, "mla": _mla_cfg, "windowed": _windowed_cfg,
+        "windowed2": lambda: _windowed_cfg(n_layers=4)}
 
 
 def _servables(cfg, page_size=8):
@@ -190,7 +194,7 @@ def test_paged_engine_bitexact(pair):
     name, dense, paged = pair
     eng_d, res_d = _drain(dense, PROMPTS)
     eng_p, res_p = _drain(paged, PROMPTS)
-    if name == "windowed":
+    if name.startswith("windowed"):
         # only the global layer pages; windowed layers stay slot-dense
         assert eng_p.kv_layout == "paged"
     for rd, rp in zip(res_d, res_p):

@@ -278,34 +278,77 @@ def test_flash_decode_mha_and_scalar_pos():
                                atol=1e-5)
 
 
-def test_paged_flash_decode_bit_exact_vs_dense_view():
-    """The paged kernel gathers KV pages in place; over the same page
-    geometry it must be BIT-exact vs the flash kernel run on the
-    paged_view dense reassembly with matching split boundaries."""
-    rng = np.random.RandomState(8)
+def _paged_case(rng, n_layers=None):
+    """Pools (n_pages, ps, Hkv*D) -- stacked (n_layers, ...) with distinct
+    contents per layer when ``n_layers`` is set -- two slots' page tables
+    (one with a hole), their pos maps, positions and queries."""
     b, npg, ps, hkv, hq, d = 2, 4, 8, 2, 4, 16
     n_pages = b * npg + 3
-    kp = jnp.asarray(rng.randn(n_pages, ps, hkv, d).astype(np.float32))
-    vp = jnp.asarray(rng.randn(n_pages, ps, hkv, d).astype(np.float32))
+    lead = () if n_layers is None else (n_layers,)
+    kp = jnp.asarray(rng.randn(*lead, n_pages, ps, hkv * d).astype(
+        np.float32))
+    vp = jnp.asarray(rng.randn(*lead, n_pages, ps, hkv * d).astype(
+        np.float32))
     # slot 0: 3 mapped pages + 1 hole; slot 1: 2 mapped pages
     table = jnp.asarray([[2, 5, 7, -1], [1, 9, -1, -1]], jnp.int32)
     pos_map = np.full((b, npg * ps), -1, np.int32)
     pos_map[0, : 3 * ps] = np.arange(3 * ps)
     pos_map[1, : 2 * ps] = np.arange(2 * ps)
-    pos_map = jnp.asarray(pos_map)
     pos = jnp.asarray([3 * ps - 1, ps + 3], jnp.int32)
     q = jnp.asarray(rng.randn(b, 1, hq, d).astype(np.float32))
+    return q, kp, vp, table, jnp.asarray(pos_map), pos, (hkv, d)
 
-    out_paged = paged_flash_decode(q, kp, vp, table, pos_map, pos)
-    k_view = paged_view(kp, table, pos_map)
-    v_view = paged_view(vp, table, pos_map)
-    out_view = flash_decode(q, k_view, v_view, pos_map, pos, kv_split=npg)
-    assert np.array_equal(np.asarray(out_paged), np.asarray(out_view)), \
-        "paged flash decode must be bit-exact vs the dense-view flash path"
-    # and allclose vs the XLA reference over the same view
-    out_ref = decode_attention(q, k_view, v_view, pos_map, pos)
-    np.testing.assert_allclose(np.asarray(out_paged), np.asarray(out_ref),
-                               atol=1e-5)
+
+def _check_paged_vs_dense_view(n_layers):
+    q, kp, vp, table, pos_map, pos, (hkv, d) = _paged_case(
+        np.random.RandomState(8), n_layers)
+    b, npg = table.shape
+    for layer in ([None] if n_layers is None else range(n_layers)):
+        out_paged = paged_flash_decode(q, kp, vp, table, pos_map, pos,
+                                       layer=layer)
+        shape = (b, pos_map.shape[1], hkv, d)
+        k_view = paged_view(kp, table, pos_map, layer).reshape(shape)
+        v_view = paged_view(vp, table, pos_map, layer).reshape(shape)
+        out_view = flash_decode(q, k_view, v_view, pos_map, pos,
+                                kv_split=npg)
+        assert np.array_equal(np.asarray(out_paged), np.asarray(out_view)), \
+            "paged flash decode must be bit-exact vs the dense-view path"
+        # and allclose vs the XLA reference over the same view
+        out_ref = decode_attention(q, k_view, v_view, pos_map, pos)
+        np.testing.assert_allclose(np.asarray(out_paged),
+                                   np.asarray(out_ref), atol=1e-5)
+
+
+def test_paged_flash_decode_bit_exact_vs_dense_view():
+    """The paged kernel gathers KV pages in place; over the same page
+    geometry it must be BIT-exact vs the flash kernel run on the
+    paged_view dense reassembly with matching split boundaries."""
+    _check_paged_vs_dense_view(None)
+
+
+@pytest.mark.parametrize("n_layers", [2, 3])
+def test_paged_flash_decode_stacked_bit_exact_vs_dense_view(n_layers):
+    """The same over a stacked pool (the layer scan's carry): at every
+    layer index, against that layer's dense view."""
+    _check_paged_vs_dense_view(n_layers)
+
+
+def test_paged_flash_decode_layer_index_reads_that_layer():
+    """A stacked pool read at layer ``l`` is bit-exact against the same
+    call on layer ``l``'s pool alone, and the layers' outputs differ, so a
+    wrong or ignored index cannot pass."""
+    q, kp, vp, table, pos_map, pos, _ = _paged_case(
+        np.random.RandomState(12), n_layers=3)
+    outs = []
+    for layer in range(3):
+        stacked = paged_flash_decode(q, kp, vp, table, pos_map, pos,
+                                     layer=jnp.int32(layer))
+        alone = paged_flash_decode(q, kp[layer], vp[layer], table, pos_map,
+                                   pos)
+        assert np.array_equal(np.asarray(stacked), np.asarray(alone)), layer
+        outs.append(np.asarray(stacked))
+    assert not np.array_equal(outs[0], outs[1])
+    assert not np.array_equal(outs[1], outs[2])
 
 
 def test_paged_flash_decode_ignores_stale_pages():
@@ -313,8 +356,8 @@ def test_paged_flash_decode_ignores_stale_pages():
     visibility."""
     rng = np.random.RandomState(9)
     b, npg, ps, hkv, hq, d = 1, 2, 4, 2, 2, 8
-    kp = jnp.asarray(rng.randn(6, ps, hkv, d).astype(np.float32))
-    vp = jnp.asarray(rng.randn(6, ps, hkv, d).astype(np.float32))
+    kp = jnp.asarray(rng.randn(6, ps, hkv * d).astype(np.float32))
+    vp = jnp.asarray(rng.randn(6, ps, hkv * d).astype(np.float32))
     table = jnp.asarray([[3, -1]], jnp.int32)
     pos_map = np.full((b, npg * ps), -1, np.int32)
     pos_map[0, :ps] = np.arange(ps)
@@ -465,6 +508,47 @@ def test_servable_decode_kernel_flash_parity():
         tx = jnp.argmax(lx[:, 0], -1)[:, None].astype(jnp.int32)
         tf = jnp.argmax(lf[:, 0], -1)[:, None].astype(jnp.int32)
         np.testing.assert_array_equal(np.asarray(tx), np.asarray(tf))
+
+
+@pytest.mark.slow
+def test_servable_decode_kernel_flash_parity_paged():
+    """The same over a paged cache. gemma3's two scan periods of (local,
+    local, global) stack the global layers' pools in the layer scan's
+    carry, so a kernel or write that picks the wrong layer's pool fails;
+    the XLA paged decode stays bit-exact against the dense cache."""
+    from repro.models.common import PagedLayout
+    from repro.serving.servable import prepare_servable
+    from repro.serving.spec import ServingSpec
+    cfg, params = _smoke_setup()
+    assert cfg.layer_plan()[2] >= 2
+    mk = lambda dk: ServingSpec(tile=(16, 16), sparsity=0.5,
+                                prune="oneshot", targets=_ATTN,
+                                backend="plan", decode_kernel=dk)
+    sv_x = prepare_servable(params, cfg, mk("xla"))
+    sv_f = prepare_servable(params, cfg, mk("flash"))
+    layout = PagedLayout(page_size=8, n_pages=2 * 32 // 8)
+
+    def paged(sv):          # slot b owns pages 4b..4b+3, all mapped
+        return jax.tree_util.tree_map_with_path(
+            lambda p, x: jnp.broadcast_to(
+                jnp.arange(x.shape[-2] * x.shape[-1], dtype=x.dtype
+                           ).reshape(x.shape[-2:]), x.shape)
+            if getattr(p[-1], "key", None) == "page_table" else x,
+            sv.init_cache(2, 32, paged=layout))
+
+    cd, cx, cf = sv_x.init_cache(2, 32), paged(sv_x), paged(sv_f)
+    t = jnp.asarray([[3], [7]], jnp.int32)
+    for step in range(12):              # crosses the first page boundary
+        p = jnp.full((2,), step, jnp.int32)
+        ld, cd = sv_x.decode_step(cd, t, p)
+        lx, cx = sv_x.decode_step(cx, t, p)
+        lf, cf = sv_f.decode_step(cf, t, p)
+        np.testing.assert_array_equal(np.asarray(lx), np.asarray(ld))
+        np.testing.assert_allclose(np.asarray(lf), np.asarray(lx),
+                                   atol=1e-4)
+        t = jnp.argmax(lx[:, 0], -1)[:, None].astype(jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(t), np.asarray(jnp.argmax(lf[:, 0], -1)[:, None]))
 
 
 @pytest.mark.slow

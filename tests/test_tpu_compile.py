@@ -117,20 +117,78 @@ def test_flash_decode_compiles(one_chip, hkv, g, d):
         s((b, t), jnp.int32), s((b,), jnp.int32))
 
 
-def _paged_flash_decode(one_chip):
-    b, hkv, g, d, ps, npg, dt = 4, 4, 2, 256, 16, 64, jnp.bfloat16
-    n_pages = b * npg
+#: paged decode widths: the benchmark's DeepSeek-LLM-7B (32 MHA KV heads
+#: of 128, pages of 128, 16 slots x 12 pages, 8 stacked layers) and
+#: gemma3-4b's (4 KV heads x 2 of 256, pages of 16)
+PAGED_WIDTHS = {"deepseek_7b": (16, 32, 1, 128, 128, 12, 8),
+                "gemma3_4b": (4, 4, 2, 256, 16, 64, 2)}
+
+
+def _paged_flash_decode(one_chip, widths="deepseek_7b"):
+    """The kernel as the layer scan calls it: stacked (P, pages, ps,
+    Hkv*D) pools read at a traced layer index."""
+    b, hkv, g, d, ps, npg, n_layers = PAGED_WIDTHS[widths]
+    dt = jnp.bfloat16
+    pool = (n_layers, b * npg, ps, hkv * d)
     s = lambda shape, dtype=dt: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=one_chip)
-    return _compile(lambda q, kp, vp, pt, pm, pos: paged_flash_decode(
-        q, kp, vp, pt, pm, pos, interpret=False),
-        s((b, 1, hkv * g, d)), s((n_pages, ps, hkv, d)),
-        s((n_pages, ps, hkv, d)), s((b, npg), jnp.int32),
-        s((b, npg * ps), jnp.int32), s((b,), jnp.int32))
+    return _compile(lambda q, kp, vp, pt, pm, pos, layer: paged_flash_decode(
+        q, kp, vp, pt, pm, pos, layer=layer, interpret=False),
+        s((b, 1, hkv * g, d)), s(pool), s(pool), s((b, npg), jnp.int32),
+        s((b, npg * ps), jnp.int32), s((b,), jnp.int32), s((), jnp.int32))
 
 
 def test_paged_flash_decode_compiles(one_chip):
-    _paged_flash_decode(one_chip)
+    for widths in PAGED_WIDTHS:
+        _paged_flash_decode(one_chip, widths)
+
+
+def test_paged_decode_window_moves_no_pool(one_chip, monkeypatch):
+    """The fused decode window with the compiled paged flash kernel, for a
+    small Llama-style model whose pools are deployment-sized (17 MB a
+    layer: a pool small enough to sit in VMEM gets prefetched there
+    whole): the optimized TPU HLO holds no copy, slice, update-slice or
+    reshape of a layer's pool or of the stacked pool
+    (tests/test_kv_pool_in_place.py checks the CPU paths)."""
+    import importlib
+    from repro.configs.base import LayerKind, ModelConfig
+    from repro.kernels.flash_decode import decode_kernel_override
+    from repro.models import api as model_api
+    from repro.models.common import PagedLayout
+    from test_kv_pool_in_place import pool_moves_in_hlo
+    fd = importlib.import_module("repro.kernels.flash_decode")
+    monkeypatch.setattr(fd, "pallas_interpret_default",
+                        lambda interpret=None: False)
+    cfg = ModelConfig(
+        arch="pool-tpu", family="dense", n_layers=3, d_model=256,
+        n_heads=4, n_kv_heads=2, head_dim=128, d_ff=512, vocab_size=512,
+        pattern=(LayerKind("attn", "dense"),), dtype="bfloat16")
+    slots, cache_len, layout = 4, 8192, PagedLayout(128, 4 * 64 + 3)
+    params = jax.eval_shape(lambda: model_api.init_model(
+        jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: model_api.init_cache(
+        None, cfg, slots, cache_len, paged=layout))
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    pools = {x.shape for p, x in jax.tree_util.tree_leaves_with_path(cache)
+             if str(getattr(p[-1], "key", "")).endswith("_pages")}
+    assert pools == {(3, 259, 128, 256)}
+    shapes = pools | {s[1:] for s in pools}
+
+    def fused(p, c, t, s):
+        with decode_kernel_override("flash"):
+            return model_api.decode_many(p, c, cfg, t, s, 4)
+    # the cache donated, as the engine's window is: an undonated one is
+    # copied once into the buffer the window then updates in place
+    hlo = jax.jit(fused, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        *on_chip((jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                  jax.ShapeDtypeStruct((slots,), jnp.int32)))
+    ).compile().as_text()
+    assert "_paged_flash_call" in hlo
+    found = pool_moves_in_hlo(hlo, shapes)
+    assert not found, found
 
 
 def _needles(family):
