@@ -23,6 +23,24 @@ class LayerKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (``rope_scaling`` with ``type: yarn``): frequencies blended between
+    interpolated (divided by ``factor``) and original by a ramp over the
+    dims that ``beta_fast``/``beta_slow`` rotations at
+    ``original_max_position`` bound; the attention scale is multiplied by
+    mscale(factor, mscale_all_dim)^2 and cos/sin by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     arch: str
     family: str                      # dense|moe|ssm|hybrid|audio|vlm|bert
@@ -38,12 +56,17 @@ class ModelConfig:
     pattern: Tuple[LayerKind, ...] = (LayerKind(),)
     prefix: Tuple[LayerKind, ...] = ()     # unrolled leading layers
 
-    # MoE
+    # MoE: the router scores all ``n_experts``; this device holds experts
+    # expert_offset .. expert_offset + experts_held - 1 (0 = all of them,
+    # the training layout) and computes their part of the layer alone
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = True      # renormalize the top-k gate weights
+    routed_scaling_factor: float = 1.0
     router_aux_coef: float = 0.01
 
     # MLA (DeepSeek-V2)
@@ -64,6 +87,7 @@ class ModelConfig:
 
     # attention details
     rope_theta: float = 10000.0
+    rope_yarn: Optional[YarnRope] = None    # MLA rope scaling (DeepSeek-V2)
     rotary_fraction: float = 1.0    # chatglm "2d" rope = 0.5
     qk_norm: bool = False           # qwen3
     scale_embedding: bool = False   # gemma
@@ -96,6 +120,11 @@ class ModelConfig:
     @property
     def jdtype(self):
         return jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this device holds (0 without MoE)."""
+        return self.experts_held or self.n_experts
 
     @property
     def q_dim(self) -> int:

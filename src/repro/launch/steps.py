@@ -81,8 +81,8 @@ def make_prefill_step(cfg: ModelConfig, packs=None):
 
 def make_serve_step(cfg: ModelConfig, packs=None):
     def serve_step(params, cache, token, pos):
-        logits, new_cache = model_decode_step(params, cache, cfg, token, pos,
-                                              packs=packs)
+        logits, new_cache, _ = model_decode_step(params, cache, cfg, token,
+                                                 pos, packs=packs)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return nxt[:, None], new_cache
     return serve_step

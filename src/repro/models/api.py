@@ -73,11 +73,14 @@ def shard_cache(cache, cfg: ModelConfig, mesh):
 
 
 def decode_step(params, cache, cfg: ModelConfig, token, pos, packs=None):
-    """``pos``: scalar (single-request convention, broadcast) or int32 (B,)
-    ragged per-slot positions; rows with pos < 0 are inactive slots whose
-    cache state is left untouched (continuous batching, docs/API.md)."""
+    """-> (logits, cache, MoE rows: (n_held,) int32 assignments each held
+    expert computed; zero length without MoE). ``pos``: scalar
+    (single-request convention, broadcast) or int32 (B,) ragged per-slot
+    positions; rows with pos < 0 are inactive slots whose cache state is
+    left untouched (continuous batching, docs/API.md)."""
     if cfg.family == "audio":
-        return encdec_mod.decode_step(params, cache, cfg, token, pos)
+        logits, cache = encdec_mod.decode_step(params, cache, cfg, token, pos)
+        return logits, cache, jnp.zeros((cfg.n_held,), jnp.int32)
     if cfg.family == "bert":
         raise ValueError("encoder-only arch has no decode step")
     return lm_mod.decode_step(params, cache, cfg, token, pos, packs=packs)
@@ -116,7 +119,9 @@ def decode_many(params, cache, cfg: ModelConfig, token, pos, n_steps, *,
     Returns ``(tokens (K, B) int32, valid (K, B) bool, state)`` where
     ``valid[k, b]`` marks tokens actually emitted by live slots and
     ``state`` is the carry to continue from:
-    ``{'token', 'pos', 'remaining', 'failed', 'cache'}``.
+    ``{'token', 'pos', 'remaining', 'failed', 'cache'}``, with
+    ``'moe_rows'``, the window's MoE rows (:func:`decode_step`) summed
+    over its steps.
     """
     b = token.shape[0]
     pos = as_slot_positions(pos, b)
@@ -134,8 +139,8 @@ def decode_many(params, cache, cfg: ModelConfig, token, pos, n_steps, *,
     failed0 = jnp.zeros((b,), bool)
 
     def body(carry, _):
-        tok, p, rem, bad, c = carry
-        logits, c = decode_step(params, c, cfg, tok, p, packs=packs)
+        tok, p, rem, bad, c, moe_rows = carry
+        logits, c, r = decode_step(params, c, cfg, tok, p, packs=packs)
         rows = logits[:, 0, :]
         finite = jnp.isfinite(rows).all(axis=-1)
         nxt = sample_tokens(rows, key, p, temperature=temperature,
@@ -149,12 +154,15 @@ def decode_many(params, cache, cfg: ModelConfig, token, pos, n_steps, *,
         new_pos = jnp.where(done | poisoned, -1,
                             jnp.where(active, p + 1, p))
         new_tok = jnp.where(emit, nxt, tok[:, 0])[:, None]
-        return (new_tok, new_pos, rem, bad | poisoned, c), (nxt, emit)
+        return (new_tok, new_pos, rem, bad | poisoned, c, moe_rows + r), \
+            (nxt, emit)
 
-    (token, pos, remaining, failed, cache), (toks, valid) = jax.lax.scan(
-        body, (token, pos, remaining, failed0, cache), None, length=n_steps)
+    (token, pos, remaining, failed, cache, moe_rows), (toks, valid) = \
+        jax.lax.scan(body, (token, pos, remaining, failed0, cache,
+                            jnp.zeros((cfg.n_held,), jnp.int32)),
+                     None, length=n_steps)
     state = {"token": token, "pos": pos, "remaining": remaining,
-             "failed": failed, "cache": cache}
+             "failed": failed, "cache": cache, "moe_rows": moe_rows}
     return toks, valid, state
 
 
@@ -163,7 +171,8 @@ def prefill_cache(params, cache, cfg: ModelConfig, tokens, length=None,
     """One-pass prompt prefill into a decode cache (lm-family layouts):
     forward-path compute for tokens (B, S), bulk cache writes for positions
     0..length-1 (length <= S; the tail is bucket padding). Returns
-    (logits (B, S, V), cache). Audio prefills through the scanned decode
+    (logits (B, S, V), cache, MoE rows as :func:`decode_step`'s). Audio
+    prefills through the scanned decode
     path instead (its decoder prompts are BOS-sized)."""
     if cfg.family in ("audio", "bert"):
         raise ValueError(f"no one-pass prefill for family {cfg.family!r}")
@@ -234,7 +243,8 @@ def prefill_suffix(params, cache, cfg: ModelConfig, tokens, slot, start,
     """Prefill only the suffix of a prompt whose first ``start`` tokens are
     already resident in slot ``slot`` of the batched engine cache: the
     paged shared-prefix path (prefix-cache hit) and the dense-KV chunked-
-    prefill path share this entry point (see lm.prefill_suffix)."""
+    prefill path share this entry point (see lm.prefill_suffix). Returns
+    :func:`prefill_cache`'s three values."""
     if cfg.family in ("audio", "bert"):
         raise ValueError(f"no one-pass prefill for family {cfg.family!r}")
     return lm_mod.prefill_suffix(params, cache, cfg, tokens, slot, start,

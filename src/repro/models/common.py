@@ -6,6 +6,8 @@ uniformly.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -51,22 +53,54 @@ def act_fn(name):
 
 # -- RoPE ---------------------------------------------------------------------
 
-def rope_freqs(head_dim, rotary_fraction=1.0, theta=10000.0):
+def rope_freqs(head_dim, rotary_fraction=1.0, theta=10000.0, yarn=None):
     rot = int(head_dim * rotary_fraction)
     rot -= rot % 2
     inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    if yarn is not None:
+        # YaRN: the dims below the beta_fast correction dim keep their
+        # frequency, those above the beta_slow one are interpolated by
+        # ``factor``, a linear ramp blends the dims between
+        low, high = yarn_correction_range(yarn, rot, theta)
+        ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 0.001), 0.0, 1.0)
+        inv = inv / yarn.factor * ramp + inv * (1.0 - ramp)
     return inv, rot
 
 
-def apply_rope(x, positions, *, theta=10000.0, rotary_fraction=1.0):
+def yarn_correction_range(yarn, dim, theta):
+    """The rope dims (of ``dim // 2`` frequencies) between which YaRN's
+    ramp runs: those that turn ``beta_fast`` and ``beta_slow`` times over
+    the original context, floored and ceiled into [0, dim - 1]."""
+    def at(rotations):
+        return dim * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi)) / (
+                                  2 * math.log(theta))
+    return (max(math.floor(at(yarn.beta_fast)), 0),
+            min(math.ceil(at(yarn.beta_slow)), dim - 1))
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature factor 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, *, theta=10000.0, rotary_fraction=1.0,
+               yarn=None):
     """x: (..., S, H, D); positions: (..., S) int32. Pairs (x_i, x_{i+rot/2})
-    rotated; trailing (1-fraction) dims pass through (chatglm-style partial)."""
+    rotated; trailing (1-fraction) dims pass through (chatglm-style partial).
+    ``yarn`` (configs.base.YarnRope): YaRN frequencies, cos and sin scaled
+    by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     d = x.shape[-1]
-    inv, rot = rope_freqs(d, rotary_fraction, theta)
+    inv, rot = rope_freqs(d, rotary_fraction, theta, yarn)
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     ang = positions[..., None].astype(jnp.float32) * inv          # (..., S, rot/2)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

@@ -85,11 +85,29 @@ def init_lm(key, cfg: ModelConfig):
 # layer application
 # ---------------------------------------------------------------------------
 
+def _no_rows(cfg):
+    """The MoE row counter of a layer that routes nothing."""
+    return jnp.zeros((cfg.n_held,), jnp.int32)
+
+
+def _real_tokens(b, s, pos, prefill_len):
+    """(B, S) bool of the tokens a step really computes: decode rows of
+    active slots, prefill positions below the prompt length (None: all)."""
+    if pos is not None:
+        return jnp.broadcast_to((attn.as_slot_positions(pos, b) >= 0)[:, None],
+                                (b, s))
+    if prefill_len is not None:
+        return jnp.broadcast_to(jnp.arange(s)[None, :] < prefill_len, (b, s))
+    return None
+
+
 def _apply_layer(p, h, cfg, kind: LayerKind, *, positions, cache=None,
                  pos=None, packs=None, prefill_len=None, page_slot=None,
                  page_start=None, layer=None):
+    """-> (h, new cache, aux loss, MoE rows (n_held,) int32)."""
     hn = apply_norm(p["norm1"], h, cfg.norm)
     aux = jnp.zeros((), jnp.float32)
+    rows = _no_rows(cfg)
     mix_packs = _layer_packs(packs, "attn") or _layer_packs(packs, "mixer")
     if kind.mixer in ("attn", "local"):
         out, new_mix_cache = attn.apply_attention(
@@ -123,13 +141,16 @@ def _apply_layer(p, h, cfg, kind: LayerKind, *, positions, cache=None,
     if kind.ffn != "none" and "ffn" in p:
         hn = apply_norm(p["norm2"], h, cfg.norm)
         if kind.ffn == "moe":
-            out, aux = moe_mod.apply_moe(p["ffn"], hn, cfg)
+            out, aux, rows = moe_mod.apply_moe(
+                p["ffn"], hn, cfg, packs=_layer_packs(packs, "ffn"),
+                valid=_real_tokens(*hn.shape[:2], pos, prefill_len),
+                layer=layer)
         else:
             out = apply_mlp(p["ffn"], hn, cfg.act,
                             packs=_layer_packs(packs, "ffn"))
         h = h + out
     new_cache = {"mix": new_mix_cache} if cache is not None else None
-    return h, new_cache, aux
+    return h, new_cache, aux, rows
 
 
 def _layer_packs(packs, scope):
@@ -149,9 +170,7 @@ def forward(params, cfg: ModelConfig, tokens, *, mm_embeds=None, packs=None):
     """tokens (B, S) -> logits (B, S, V) f32, aux loss."""
     prefix, pattern, n_periods, suffix = cfg.layer_plan()
     b, s = tokens.shape
-    h = jnp.take(params["embed"]["w"], tokens, axis=0)
-    if cfg.scale_embedding:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    h = _embed(params, cfg, tokens)
     if mm_embeds is not None:   # vlm: patch embeddings occupy the prefix slots
         p = mm_embeds.shape[1]
         h = jnp.concatenate([mm_embeds.astype(h.dtype), h[:, p:]], axis=1)
@@ -159,18 +178,18 @@ def forward(params, cfg: ModelConfig, tokens, *, mm_embeds=None, packs=None):
     aux = jnp.zeros((), jnp.float32)
 
     for i, kind in enumerate(prefix):
-        h, _, a = _apply_layer(params["prefix"][i], h, cfg, kind,
-                               positions=positions,
-                               packs=_layer_packs(packs, f"prefix/{i}"))
+        h, _, a, _ = _apply_layer(params["prefix"][i], h, cfg, kind,
+                                  positions=positions,
+                                  packs=_layer_packs(packs, f"prefix/{i}"))
         aux += a
 
     if n_periods > 0:
         def body(carry, xs):
             h, aux = carry
             for i, kind in enumerate(pattern):
-                h, _, a = _apply_layer(xs[i], h, cfg, kind,
-                                       positions=positions,
-                                       packs=_layer_packs(packs, f"blocks/{i}"))
+                h, _, a, _ = _apply_layer(
+                    xs[i], h, cfg, kind, positions=positions,
+                    packs=_layer_packs(packs, f"blocks/{i}"))
                 aux += a
             return (h, aux), None
         # NOTE §Perf iter 4 (refuted): a save_only_these_names("mixer_out")
@@ -181,16 +200,12 @@ def forward(params, cfg: ModelConfig, tokens, *, mm_embeds=None, packs=None):
         (h, aux), _ = jax.lax.scan(body, (h, aux), params["blocks"])
 
     for i, kind in enumerate(suffix):
-        h, _, a = _apply_layer(params["suffix"][i], h, cfg, kind,
-                               positions=positions,
-                               packs=_layer_packs(packs, f"suffix/{i}"))
+        h, _, a, _ = _apply_layer(params["suffix"][i], h, cfg, kind,
+                                  positions=positions,
+                                  packs=_layer_packs(packs, f"suffix/{i}"))
         aux += a
 
-    h = apply_norm(params["final_norm"], h, cfg.norm)
-    head = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
-    logits = jnp.einsum("bsd,vd->bsv", h, head,
-                        preferred_element_type=jnp.float32)
-    return logits, aux
+    return _head(params, cfg, h), aux
 
 
 # ---------------------------------------------------------------------------
@@ -270,35 +285,99 @@ def _join_pools(rest, pools):
     return {"mix": {**rest["mix"], **pools}}
 
 
+#: a MoE layer's routed experts, the ``ffn`` leaves read by the layer index
+_ROUTED = ("wi", "wg", "wo")
+
+
+def _split_routed(p, kind: LayerKind):
+    """One layer's params -> (them without the routed experts, the routed
+    experts: empty but for a MoE layer)."""
+    if kind.ffn != "moe" or "ffn" not in p:
+        return p, {}
+    ffn = p["ffn"]
+    return ({**p, "ffn": {k: v for k, v in ffn.items() if k not in _ROUTED}},
+            {k: ffn[k] for k in _ROUTED})
+
+
 def _scan_blocks(params, cache, cfg: ModelConfig, h, packs, **layer_kw):
     """Run the scan-stacked ``blocks`` layers over ``h`` with their caches
     -> (h, new block caches). Page pools ride in the scan carry, stacked
     (P, n_pages, ...), and each layer reads and writes its own through the
     layer index: scanned as ``xs``/``ys`` instead, every layer of every
     step would slice its pool out and stack it back, whole-pool copies
-    where one row per slot changes. Everything else (page tables, pos
-    maps, rings, recurrent state) is small and scans as ``xs``/``ys``."""
+    where one row per slot changes. The routed experts of MoE layers stay
+    stacked in the same way, read at the layer index by ``moe_gmm``: as
+    ``xs``, each layer would copy its experts out of the stack for the
+    kernel. Everything else (weights, page tables, pos maps, rings,
+    recurrent state) scans as ``xs``/``ys``."""
     _, pattern, n_periods, _ = cfg.layer_plan()
     pools, rest = zip(*map(_split_pools, cache["blocks"]))
+    block_ps, routed = zip(*map(_split_routed, params["blocks"], pattern))
 
     def body(carry, xs):
-        h, pools = carry
+        h, pools, rows = carry
         layer_ps, layer_cs, l = xs
         new_pools, new_cs = [], []
         for i, kind in enumerate(pattern):
-            h, c, _ = _apply_layer(layer_ps[i], h, cfg, kind,
-                                   cache=_join_pools(layer_cs[i], pools[i]),
-                                   layer=l,
-                                   packs=_layer_packs(packs, f"blocks/{i}"),
-                                   **layer_kw)
-            p, r = _split_pools(c)
+            p = layer_ps[i]
+            if routed[i]:
+                p = {**p, "ffn": {**p["ffn"], **routed[i]}}
+            h, c, _, r = _apply_layer(
+                p, h, cfg, kind,
+                cache=_join_pools(layer_cs[i], pools[i]), layer=l,
+                packs=_layer_packs(packs, f"blocks/{i}"), **layer_kw)
+            rows = rows + r
+            p, c = _split_pools(c)
             new_pools.append(p)
-            new_cs.append(r)
-        return (h, tuple(new_pools)), tuple(new_cs)
+            new_cs.append(c)
+        return (h, tuple(new_pools), rows), tuple(new_cs)
 
-    (h, pools), rest = jax.lax.scan(
-        body, (h, pools), (params["blocks"], rest, jnp.arange(n_periods)))
-    return h, tuple(map(_join_pools, rest, pools))
+    (h, pools, rows), rest = jax.lax.scan(
+        body, (h, pools, _no_rows(cfg)),
+        (block_ps, rest, jnp.arange(n_periods)))
+    return h, tuple(map(_join_pools, rest, pools)), rows
+
+
+def _layers(params, cache, cfg: ModelConfig, h, packs, **layer_kw):
+    """Every layer over ``h`` with its cache: the unrolled prefix, the
+    scanned blocks, the unrolled suffix -> (h, new cache, MoE rows summed
+    over the layers)."""
+    prefix, _, n_periods, suffix = cfg.layer_plan()
+
+    def unrolled(sec, kinds, h, rows):
+        out = []
+        for i, kind in enumerate(kinds):
+            h, c, _, r = _apply_layer(params[sec][i], h, cfg, kind,
+                                      cache=cache[sec][i],
+                                      packs=_layer_packs(packs, f"{sec}/{i}"),
+                                      **layer_kw)
+            rows = rows + r
+            out.append(c)
+        return h, tuple(out), rows
+
+    h, new_prefix, rows = unrolled("prefix", prefix, h, _no_rows(cfg))
+    new_blocks = cache["blocks"]
+    if n_periods > 0:
+        h, new_blocks, r = _scan_blocks(params, cache, cfg, h, packs,
+                                        **layer_kw)
+        rows = rows + r
+    h, new_suffix, rows = unrolled("suffix", suffix, h, rows)
+    return h, {"prefix": new_prefix, "blocks": new_blocks,
+               "suffix": new_suffix}, rows
+
+
+def _embed(params, cfg: ModelConfig, tokens):
+    h = jnp.take(params["embed"]["w"], tokens, axis=0)
+    if cfg.scale_embedding:
+        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    return h
+
+
+def _head(params, cfg: ModelConfig, h):
+    h = apply_norm(params["final_norm"], h, cfg.norm)
+    head = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
+    return jnp.einsum("bsd,vd->bsv", h, head,
+                      preferred_element_type=jnp.float32)
 
 
 def reset_slot(cache, slot):
@@ -413,48 +492,24 @@ def restore_slot_paged(cache, slot, page_row, resume_len):
 
 
 def decode_step(params, cache, cfg: ModelConfig, token, pos, *, packs=None):
-    """token (B, 1) + caches at absolute position ``pos`` -> (logits, cache).
+    """token (B, 1) + caches at absolute position ``pos`` -> (logits, cache,
+    MoE rows).
 
     ``pos`` is a scalar (every row at the same position -- the single-request
     convention) or an int32 (B,) vector of ragged per-slot positions: each
     batch row is an independent request slot with its own causal/window mask
     and cache write slot. Rows with ``pos < 0`` are inactive -- their cache
-    state is left untouched and their logits are meaningless.
+    state is left untouched and their logits are meaningless. The MoE rows
+    are (n_held,) int32 assignments each held expert computed, summed over
+    the layers (zero length without MoE).
     """
-    prefix, _, n_periods, suffix = cfg.layer_plan()
     b = token.shape[0]
-    h = jnp.take(params["embed"]["w"], token, axis=0)
-    if cfg.scale_embedding:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    h = _embed(params, cfg, token)
     pos = attn.as_slot_positions(pos, b)
     positions = jnp.maximum(pos, 0)[:, None]          # (B, 1), rope-safe
-
-    new_prefix = []
-    for i, kind in enumerate(prefix):
-        h, c, _ = _apply_layer(params["prefix"][i], h, cfg, kind,
-                               positions=positions, cache=cache["prefix"][i],
-                               pos=pos, packs=_layer_packs(packs, f"prefix/{i}"))
-        new_prefix.append(c)
-
-    new_blocks = cache["blocks"]
-    if n_periods > 0:
-        h, new_blocks = _scan_blocks(params, cache, cfg, h, packs,
-                                     positions=positions, pos=pos)
-
-    new_suffix = []
-    for i, kind in enumerate(suffix):
-        h, c, _ = _apply_layer(params["suffix"][i], h, cfg, kind,
-                               positions=positions, cache=cache["suffix"][i],
-                               pos=pos, packs=_layer_packs(packs, f"suffix/{i}"))
-        new_suffix.append(c)
-
-    h = apply_norm(params["final_norm"], h, cfg.norm)
-    head = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
-    logits = jnp.einsum("bsd,vd->bsv", h, head,
-                        preferred_element_type=jnp.float32)
-    new_cache = {"prefix": tuple(new_prefix), "blocks": new_blocks,
-                 "suffix": tuple(new_suffix)}
-    return logits, new_cache
+    h, new_cache, rows = _layers(params, cache, cfg, h, packs,
+                                 positions=positions, pos=pos)
+    return _head(params, cfg, h), new_cache, rows
 
 
 def prefill_suffix(params, cache, cfg: ModelConfig, tokens, slot, start,
@@ -468,47 +523,16 @@ def prefill_suffix(params, cache, cfg: ModelConfig, tokens, slot, start,
     *chunked prefill* (docs/API.md §SLO scheduling), across every decode-
     capable mixer: global/windowed attention (dense rings + paged pools),
     MLA latents, SSM state carry, RG-LRU state carry. Sample the next
-    token from ``logits[0, length - 1]`` after the final chunk."""
-    prefix, _, n_periods, suffix = cfg.layer_plan()
-    b, s = tokens.shape
+    token from ``logits[0, length - 1]`` after the final chunk. Returns
+    (logits, cache, MoE rows) (:func:`decode_step`)."""
+    s = tokens.shape[1]
     length = s if length is None else length
-    h = jnp.take(params["embed"]["w"], tokens, axis=0)
-    if cfg.scale_embedding:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
     start = jnp.asarray(start, jnp.int32)
-    positions = start + jnp.arange(s)[None, :]
-
-    new_prefix = []
-    for i, kind in enumerate(prefix):
-        h, c, _ = _apply_layer(params["prefix"][i], h, cfg, kind,
-                               positions=positions, cache=cache["prefix"][i],
-                               prefill_len=length, page_slot=slot,
-                               page_start=start,
-                               packs=_layer_packs(packs, f"prefix/{i}"))
-        new_prefix.append(c)
-
-    new_blocks = cache["blocks"]
-    if n_periods > 0:
-        h, new_blocks = _scan_blocks(params, cache, cfg, h, packs,
-                                     positions=positions, prefill_len=length,
-                                     page_slot=slot, page_start=start)
-
-    new_suffix = []
-    for i, kind in enumerate(suffix):
-        h, c, _ = _apply_layer(params["suffix"][i], h, cfg, kind,
-                               positions=positions, cache=cache["suffix"][i],
-                               prefill_len=length, page_slot=slot,
-                               page_start=start,
-                               packs=_layer_packs(packs, f"suffix/{i}"))
-        new_suffix.append(c)
-
-    h = apply_norm(params["final_norm"], h, cfg.norm)
-    head = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
-    logits = jnp.einsum("bsd,vd->bsv", h, head,
-                        preferred_element_type=jnp.float32)
-    new_cache = {"prefix": tuple(new_prefix), "blocks": new_blocks,
-                 "suffix": tuple(new_suffix)}
-    return logits, new_cache
+    h, new_cache, rows = _layers(
+        params, cache, cfg, _embed(params, cfg, tokens), packs,
+        positions=start + jnp.arange(s)[None, :], prefill_len=length,
+        page_slot=slot, page_start=start)
+    return _head(params, cfg, h), new_cache, rows
 
 
 def prefill_cache(params, cache, cfg: ModelConfig, tokens, length=None, *,
@@ -518,42 +542,13 @@ def prefill_cache(params, cache, cfg: ModelConfig, tokens, length=None, *,
     whole prompt, not one per token), while every layer bulk-writes the
     state of positions 0..length-1 into ``cache``. ``length`` (<= S, traced
     OK) marks the real prompt; the tail is bucket padding and leaves no
-    trace. Returns (logits (B, S, V) f32, cache) -- sample the next token
-    from ``logits[:, length - 1]``.
+    trace. Returns (logits (B, S, V) f32, cache, MoE rows)
+    (:func:`decode_step`) -- sample the next token from
+    ``logits[:, length - 1]``.
     """
-    prefix, _, n_periods, suffix = cfg.layer_plan()
-    b, s = tokens.shape
-    length = s if length is None else length
-    h = jnp.take(params["embed"]["w"], tokens, axis=0)
-    if cfg.scale_embedding:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
-    positions = jnp.arange(s)[None, :]
-
-    new_prefix = []
-    for i, kind in enumerate(prefix):
-        h, c, _ = _apply_layer(params["prefix"][i], h, cfg, kind,
-                               positions=positions, cache=cache["prefix"][i],
-                               prefill_len=length,
-                               packs=_layer_packs(packs, f"prefix/{i}"))
-        new_prefix.append(c)
-
-    new_blocks = cache["blocks"]
-    if n_periods > 0:
-        h, new_blocks = _scan_blocks(params, cache, cfg, h, packs,
-                                     positions=positions, prefill_len=length)
-
-    new_suffix = []
-    for i, kind in enumerate(suffix):
-        h, c, _ = _apply_layer(params["suffix"][i], h, cfg, kind,
-                               positions=positions, cache=cache["suffix"][i],
-                               prefill_len=length,
-                               packs=_layer_packs(packs, f"suffix/{i}"))
-        new_suffix.append(c)
-
-    h = apply_norm(params["final_norm"], h, cfg.norm)
-    head = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
-    logits = jnp.einsum("bsd,vd->bsv", h, head,
-                        preferred_element_type=jnp.float32)
-    new_cache = {"prefix": tuple(new_prefix), "blocks": new_blocks,
-                 "suffix": tuple(new_suffix)}
-    return logits, new_cache
+    s = tokens.shape[1]
+    h, new_cache, rows = _layers(
+        params, cache, cfg, _embed(params, cfg, tokens), packs,
+        positions=jnp.arange(s)[None, :],
+        prefill_len=s if length is None else length)
+    return _head(params, cfg, h), new_cache, rows

@@ -17,7 +17,8 @@ from repro.models.attention import (_masked_row_write, as_slot_positions,
                                     paged_suffix_positions,
                                     prefill_slot_sources)
 from repro.models.common import (apply_rope, init_linear, linear,
-                                 paged_row_write, paged_view, rms_norm)
+                                 paged_row_write, paged_view, rms_norm,
+                                 yarn_mscale)
 
 
 def init_mla(key, cfg):
@@ -65,18 +66,31 @@ def _project_q(p, x, cfg, packs=None):
     return q[..., :dn], q[..., dn:]
 
 
+def softmax_scale(cfg):
+    """(qk_nope + qk_rope)^-0.5, times YaRN's mscale(factor,
+    mscale_all_dim)^2 under rope scaling (DeepSeek-V2's attention)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    y = cfg.rope_yarn
+    if y is not None:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
               prefill_len=None, page_slot=None, page_start=None, layer=None):
     """``layer`` set: the latent pools are the layer scan's stacked carry,
     this layer's at ``layer`` (attention.apply_attention)."""
     b, s, d = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    scale = softmax_scale(cfg)
     q_nope, q_rope = _project_q(p, x, cfg, packs)
-    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta,
+                        yarn=cfg.rope_yarn)
 
     c_kv = rms_norm(linear(p["w_dkv"], x), p["kv_norm"]["scale"])
     k_rope = apply_rope(linear(p["w_krope"], x)[:, :, None, :],
-                        positions, theta=cfg.rope_theta)       # (b,s,1,dr)
+                        positions, theta=cfg.rope_theta,
+                        yarn=cfg.rope_yarn)                    # (b,s,1,dr)
 
     if cache is not None and s > 1 and page_slot is not None:
         # chunk/suffix prefill: x holds ONE slot's next prompt slice at
@@ -135,7 +149,7 @@ def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
         vp = jnp.pad(v_all, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv)))
         qpos = pos_i[None, :, None]                              # (1,S,1)
         ok = (pm_row[None, None, :] >= 0) & (pm_row[None, None, :] <= qpos)
-        o = masked_attention(q, k_all, vp, ok)[..., :dv]
+        o = masked_attention(q, k_all, vp, ok, scale)[..., :dv]
         out = linear(p["wo"], o.reshape(1, s, h * dv),
                      packs and packs.get("wo"))
         return out, new_cache
@@ -150,7 +164,7 @@ def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
         # pad v to qk head dim so the shared attention kernels apply
         attn = full_attention if s <= 1024 else flash_attention
         vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv)))
-        o = attn(q, k, vp, causal=True)[..., :dv]
+        o = attn(q, k, vp, causal=True, scale=scale)[..., :dv]
         out = linear(p["wo"], o.reshape(b, s, h * dv),
                      packs and packs.get("wo"))
         if cache is None:
@@ -215,7 +229,7 @@ def apply_mla(p, x, cfg, *, positions, cache=None, pos=None, packs=None,
                        c_cache.astype(jnp.float32))
     s_rope = jnp.einsum("bqhd,btd->bhqt", q_rope.astype(jnp.float32),
                         r_cache.astype(jnp.float32))
-    scores = (s_lat + s_rope) * ((dn + dr) ** -0.5)
+    scores = (s_lat + s_rope) * scale
     ok = (pm >= 0) & (pm <= posv[:, None])              # per-row causal mask
     scores = jnp.where(ok[:, None, None, :], scores, -1e30)
     pr = jax.nn.softmax(scores, axis=-1)

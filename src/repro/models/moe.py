@@ -1,73 +1,93 @@
-"""Mixture-of-Experts FFN with capacity-based sort dispatch (EP-shardable).
+"""Mixture-of-Experts FFN over the experts this device holds, drop-free.
 
-Dispatch avoids the O(T*E) one-hot matmul: assignments are argsorted by
-expert id, positioned within their expert segment, and scattered into a
-(E, capacity, d) buffer. All heavy ops are O(T*k*d) gathers/scatters plus the
-expert einsums, and the expert dimension shards cleanly over the "model"
-mesh axis (expert parallelism). Aux load-balancing loss follows Switch.
+The router keeps its full width: float32 logits over all ``n_experts``, a
+softmax, the top ``top_k`` experts of each token, their weights
+renormalized only under ``norm_topk_prob`` and multiplied by
+``routed_scaling_factor``. The device holds experts ``expert_offset`` ..
+``expert_offset + n_held - 1`` (all of them in training) and computes their
+part of the layer alone: assignments to other experts are left out of its
+sum, as expert parallelism leaves them to the devices that hold them (the
+exchange between devices is not part of this layer). Held assignments are
+sorted into per-expert groups and run through one grouped SwiGLU matmul
+(``kernels/moe_gmm.py``); shared experts are added once.
+
+No capacity: every assignment is computed, so a token's output does not
+depend on the other tokens in its batch. Switch-style aux loss for
+training.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import init_linear, init_mlp, normal_init
+from repro.kernels.moe_gmm import group_layout, moe_gmm, row_tile
+from repro.models.common import apply_mlp, init_mlp, normal_init
 
 
 def init_moe(key, cfg):
-    d, f, e = cfg.d_model, cfg.d_ff_expert or cfg.d_ff, cfg.n_experts
+    d, f, h = cfg.d_model, cfg.d_ff_expert or cfg.d_ff, cfg.n_held
     ks = jax.random.split(key, 5)
-    p = {"router": normal_init(ks[0], (d, e), 0.02, jnp.float32),
-         "wi": normal_init(ks[1], (e, d, f), 0.02, cfg.jdtype),
-         "wg": normal_init(ks[2], (e, d, f), 0.02, cfg.jdtype),
-         "wo": normal_init(ks[3], (e, f, d), 0.02, cfg.jdtype)}
+    p = {"router": normal_init(ks[0], (d, cfg.n_experts), 0.02, jnp.float32),
+         "wi": normal_init(ks[1], (h, d, f), 0.02, cfg.jdtype),
+         "wg": normal_init(ks[2], (h, d, f), 0.02, cfg.jdtype),
+         "wo": normal_init(ks[3], (h, f, d), 0.02, cfg.jdtype)}
     if cfg.n_shared_experts:
         p["shared"] = init_mlp(ks[4], d, f * cfg.n_shared_experts,
                                cfg.act, cfg.jdtype)
     return p
 
 
-def apply_moe(p, x, cfg):
-    """x: (B, S, d) -> (y, aux_loss)."""
-    b, s, d = x.shape
-    x2 = x.reshape(b * s, d)
-    t, k, e = b * s, cfg.top_k, cfg.n_experts
-    cap = max(1, int(t * k / e * cfg.capacity_factor))
-
-    logits = jnp.einsum("td,de->te", x2.astype(jnp.float32), p["router"])
+def route(router, x2, cfg):
+    """(probs (T, E), gate (T, k), expert ids (T, k)) of tokens x2 (T, d)."""
+    logits = jnp.einsum("td,de->te", x2.astype(jnp.float32), router,
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, k)                       # (T, k)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    gate, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return probs, gate * cfg.routed_scaling_factor, idx
 
-    # ---- sort-based dispatch -------------------------------------------
-    flat_e = idx.reshape(-1)                                   # (T*k,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    seg_start = jnp.searchsorted(sorted_e, jnp.arange(e))
-    pos_in_seg = jnp.arange(t * k) - seg_start[sorted_e]       # (T*k,)
-    token_src = order // k
 
-    buf = jnp.zeros((e, cap, d), x2.dtype)
-    buf = buf.at[sorted_e, pos_in_seg].set(x2[token_src], mode="drop")
+def apply_moe(p, x, cfg, *, valid=None, packs=None, layer=None):
+    """x: (B, S, d) -> (y, aux_loss, rows): ``rows`` (n_held,) int32 counts
+    the assignments each held expert computed. ``valid`` (B, S) bool marks
+    real tokens: padding and inactive decode slots route nowhere. ``packs``
+    serve the shared experts' projections (``shared/<proj>``). With
+    ``layer``, the routed experts ``wi``/``wg``/``wo`` are stacked over the
+    layer scan's layers and read at that index (``moe_gmm``)."""
+    b, s, d = x.shape
+    t, k, held = b * s, cfg.top_k, cfg.n_held
+    x2 = x.reshape(t, d)
+    probs, gate, idx = route(p["router"], x2, cfg)
 
-    # ---- expert computation (shards over E) ----------------------------
-    act = jax.nn.silu if cfg.act == "swiglu" else jax.nn.gelu
-    h = act(jnp.einsum("ecd,edf->ecf", buf, p["wg"])) * \
-        jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    out = jnp.einsum("ecf,efd->ecd", h, p["wo"])
-
-    # ---- combine --------------------------------------------------------
-    y_flat = out.at[sorted_e, pos_in_seg].get(mode="fill", fill_value=0)
-    w_flat = gate.reshape(-1)[order]
-    y = jnp.zeros((t, d), jnp.float32).at[token_src].add(
-        y_flat.astype(jnp.float32) * w_flat[:, None])
+    local = idx - cfg.expert_offset
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine &= valid.reshape(t, 1)
+    groups = jnp.where(mine, local, held).reshape(-1).astype(jnp.int32)
+    tm = row_tile(t * k / cfg.n_experts)
+    lay = group_layout(groups, held, tm, t * min(k, held))
+    rows_x = jnp.take(x2, lay.row_src // k, axis=0, mode="fill",
+                      fill_value=0)
+    out = moe_gmm(rows_x, p["wi"], p["wg"], p["wo"], lay,       # (R, d) f32
+                  layer=layer)
+    routed = jnp.take(out, lay.dest, axis=0, mode="fill",
+                      fill_value=0).reshape(t, k, d) * gate[..., None]
+    y = routed[:, 0]
+    for j in range(1, k):               # a fixed order, whatever the batch
+        y = y + routed[:, j]
+    rows = jnp.zeros((held + 1,), jnp.int32).at[groups].add(1)[:held]
 
     if "shared" in p:
-        from repro.models.common import apply_mlp
-        y = y + apply_mlp(p["shared"], x2, cfg.act).astype(jnp.float32)
+        sp = None if packs is None else {
+            n[len("shared/"):]: v for n, v in packs.items()
+            if n.startswith("shared/")} or None
+        y = y + apply_mlp(p["shared"], x2, cfg.act,
+                          packs=sp).astype(jnp.float32)
 
     # Switch-style aux loss: E * sum(frac_tokens_e * mean_prob_e)
+    e = cfg.n_experts
     frac = jnp.mean(jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32), axis=0)
     mean_prob = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(frac * mean_prob) * cfg.router_aux_coef
-    return y.reshape(b, s, d).astype(x.dtype), aux
+    return y.reshape(b, s, d).astype(x.dtype), aux, rows

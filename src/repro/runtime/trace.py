@@ -33,6 +33,8 @@ Spans of the program and what reads them (``bench/metrics``):
     request's first admission) -- ``queue_wait_p95_ms.lm``;
   * ``repro.engine.window`` (``k``, ``slots``, ``tokens``) --
     ``decode_slot_use.lm``;
+  * both of these, in a model with MoE layers, also ``moe_rows`` and
+    ``moe_expert_slots`` (``EngineStats``) -- ``moe_rows_per_expert.lm``;
   * ``repro.engine.sync``: times ``EngineStats.sync_s``.
 """
 from __future__ import annotations

@@ -76,11 +76,6 @@ Construct via :meth:`repro.serving.Servable.engine`::
 Sampling is configured per engine (``temperature`` / ``top_k`` / ``seed``);
 the PRNG key is folded by (slot, position), so fused and per-step decoding
 emit identical tokens for the same seed (models/sampling.py).
-
-Known batching caveat: MoE layers route over the whole batch with a
-capacity limit, so token drops can depend on which slots are co-resident --
-for MoE configs the engine is still correct serving-wise but not bitwise
-equal to sequential decode (all other families are; tests/test_engine.py).
 """
 from __future__ import annotations
 
@@ -223,6 +218,11 @@ class EngineStats:
     prefill_s: float = 0.0
     decode_s: float = 0.0
     sync_s: float = 0.0
+    # MoE layers: assignments the held experts computed, and held experts x
+    # MoE layers x forwards (decode steps, prefills, prefill chunks) that
+    # could have taken them -- set also on the admit and window spans
+    moe_rows: int = 0
+    moe_expert_slots: int = 0
 
     @property
     def mean_occupancy(self) -> float:
@@ -246,7 +246,9 @@ class EngineStats:
                 "prefill_buckets": dict(self.bucket_hits),
                 "prefill_s": round(self.prefill_s, 4),
                 "decode_s": round(self.decode_s, 4),
-                "sync_s": round(self.sync_s, 4)}
+                "sync_s": round(self.sync_s, 4),
+                "moe_rows": self.moe_rows,
+                "moe_expert_slots": self.moe_expert_slots}
 
 
 class ServingEngine:
@@ -335,6 +337,10 @@ class ServingEngine:
             + list(suffix_k)
         pageable = [k for k in kinds
                     if k.mixer in ("attn", "mla") and k.window == 0]
+        n_moe = sum(k.ffn == "moe" for k in prefix_k + suffix_k) + \
+            n_per * sum(k.ffn == "moe" for k in pattern_k)
+        #: held experts x MoE layers: the expert slots of one forward
+        self._moe_slots = self.cfg.n_held * n_moe
         if layout == "paged":
             blocker = None
             if self.cfg.family == "audio":
@@ -391,8 +397,6 @@ class ServingEngine:
                 blocker = "family 'audio' prefills through the decode path"
             elif self.cfg.kv_cache_quant:
                 blocker = "kv_cache_quant (int8 KV has no masked chunk path)"
-            elif any(k.ffn == "moe" for k in kinds):
-                blocker = "MoE ffn (expert routing is batch-global)"
             if blocker is not None:
                 log.info("chunked prefill unavailable for this config "
                          "(%s); scheduling runs without it", blocker)
@@ -412,6 +416,9 @@ class ServingEngine:
         #: req_ids whose fresh full-prompt pages publish to the prefix
         #: cache once their (chunked) prefill completes
         self._pending_publish: set = set()
+        #: req_id -> (MoE rows on the device, chunks) of prefill passes
+        #: that ended mid-prompt, read with the pass that ends it
+        self._moe_carry: Dict[int, tuple] = {}
 
         self.cache = self._build_cache()
         # host-side byte accounting from the real device leaves
@@ -746,10 +753,10 @@ class ServingEngine:
                 toks[:length] = seq
                 pos_seq = np.full((bucket,), -1, np.int32)
                 pos_seq[:length] = np.arange(length)
-                sub, logits = self._prefill(self.servable.params, sub,
-                                            jnp.asarray(toks),
-                                            jnp.asarray(pos_seq),
-                                            jnp.int32(length))
+                sub, logits, moe = self._prefill(self.servable.params, sub,
+                                                 jnp.asarray(toks),
+                                                 jnp.asarray(pos_seq),
+                                                 jnp.int32(length))
                 self.cache = self._write_slot(self.cache, jnp.int32(slot),
                                               sub)
                 row = np.asarray(logits[length - 1])  # once per admission
@@ -758,6 +765,7 @@ class ServingEngine:
                 err = e
             else:
                 sp.set(tokens=length)
+                self._count_moe(sp, moe)
                 self.stats.prefills += 1
                 self.stats.prefilled_tokens += length
                 self.stats.bucket_hits[bucket] += 1
@@ -908,7 +916,7 @@ class ServingEngine:
                     self.cache = self._restore_paged(
                         self.cache, jnp.int32(slot), self._page_row(pages),
                         jnp.int32(start))
-                    self.cache, logits = self._suffix_prefill(
+                    self.cache, logits, moe = self._suffix_prefill(
                         self.servable.params, self.cache, jnp.asarray(toks),
                         jnp.int32(slot), jnp.int32(start), jnp.int32(slen))
                     row = np.asarray(logits[slen - 1])
@@ -921,7 +929,7 @@ class ServingEngine:
                     toks[:length] = seq
                     pos_seq = np.full((bucket,), -1, np.int32)
                     pos_seq[:length] = np.arange(length)
-                    sub, logits = self._prefill(
+                    sub, logits, moe = self._prefill(
                         self.servable.params, self._sub_template,
                         jnp.asarray(toks), jnp.asarray(pos_seq),
                         jnp.int32(length))
@@ -944,6 +952,7 @@ class ServingEngine:
                 err = e
             else:
                 sp.set(tokens=slen)
+                self._count_moe(sp, moe)
                 self.stats.prefills += 1
                 self.stats.bucket_hits[bucket] += 1
                 finite = bool(np.all(np.isfinite(row)))
@@ -1064,6 +1073,7 @@ class ServingEngine:
         seq = req.prompt if not req.tokens else np.concatenate(
             [req.prompt, np.asarray(req.tokens, np.int32)])
         used, row, err = 0, None, None
+        moe, chunks = self._moe_carry.pop(req.req_id, (None, 0))
         with self._admit_span(req) as sp:
             try:
                 while req.prefill_pos < req.prefill_target:
@@ -1081,10 +1091,13 @@ class ServingEngine:
                     if self._watchdog is not None:
                         self._watchdog.arm("prefill-chunk")
                     try:
-                        self.cache, logits = self._suffix_prefill(
+                        self.cache, logits, rows = self._suffix_prefill(
                             self.servable.params, self.cache,
                             jnp.asarray(toks), jnp.int32(req.slot),
                             jnp.int32(start), jnp.int32(c))
+                        if self._moe_slots:
+                            moe = rows if moe is None else moe + rows
+                            chunks += 1
                     finally:
                         if self._watchdog is not None:
                             self._watchdog.disarm()
@@ -1100,6 +1113,11 @@ class ServingEngine:
             except Exception as e:  # noqa: BLE001 -- isolate to this request
                 err = e
             sp.set(tokens=used)
+            if row is not None:
+                self._count_moe(sp, moe, chunks)
+            elif err is None and chunks:
+                # read at the sync of the pass that ends the prompt
+                self._moe_carry[req.req_id] = (moe, chunks)
         if err is not None:
             log.warning("chunked prefill of request %d failed (%s: %s)",
                         req.req_id, type(err).__name__, err)
@@ -1220,6 +1238,7 @@ class ServingEngine:
             if saved is not None:
                 self._pool.release(saved[0])
         self._pending_publish.discard(req.req_id)
+        self._moe_carry.pop(req.req_id, None)
         req.status = status
         req.failure = reason
         req.done = status == "done"
@@ -1481,7 +1500,7 @@ class ServingEngine:
             self.stats.steps += 1
             self.stats.windows += 1
             self.stats.occupancy_sum += len(decoding)
-            next_tok, ok, logits, self.cache = self._decode(
+            next_tok, ok, logits, self.cache, moe = self._decode(
                 self.servable.params, self.cache, jnp.asarray(self._tokens),
                 jnp.asarray(self._pos), self._key, self.temperature,
                 self.top_k)
@@ -1490,6 +1509,7 @@ class ServingEngine:
             rows = np.asarray(logits[:, 0, :]) if self.collect_logits \
                 else None
             sp.set(tokens=int(ok_h[decoding].sum()))
+            self._count_moe(sp, moe)
         with timed(self.stats, "sync_s", "repro.engine.sync"):
             for slot in decoding:
                 req = self._active[slot]
@@ -1502,6 +1522,16 @@ class ServingEngine:
                 req.pos += 1
                 self._emit(req, int(toks[slot]),
                            rows[slot] if rows is not None else None)
+
+    def _count_moe(self, sp, rows, forwards: int = 1) -> None:
+        """Add the MoE ``rows`` of ``forwards`` forwards, read after the
+        device call's own sync, to ``stats`` and to its span ``sp``."""
+        if not self._moe_slots:
+            return
+        n, slots = int(np.asarray(rows).sum()), self._moe_slots * forwards
+        self.stats.moe_rows += n
+        self.stats.moe_expert_slots += slots
+        sp.set(moe_rows=n, moe_expert_slots=slots)
 
     def _window_span(self, k: int):
         """The ``repro.engine.window`` span of one decode window of ``k``
@@ -1537,6 +1567,7 @@ class ServingEngine:
             self._remaining = np.array(state["remaining"], np.int32)
             emitted = int(valid_h.sum())
             sp.set(tokens=emitted)
+            self._count_moe(sp, state["moe_rows"], k)
 
         with timed(self.stats, "sync_s", "repro.engine.sync"):
             self.stats.occupancy_sum += emitted

@@ -337,8 +337,9 @@ def export_lm_sparse(params, cfg: ModelConfig, tile=(128, 128), *,
     FC targets, where most decode FLOPs live) are exported too, but ONLY
     when actually block-sparse at the kernel tile: packing an unpruned
     (100%-density) projection is pure loss, so attention-only prune
-    recipes serve their FFN dense exactly as before. MoE FFNs are skipped
-    (expert routing has no packs route).
+    recipes serve their FFN dense exactly as before. In MoE layers the
+    shared experts are packed the same way; the routed experts stay dense
+    (the grouped expert matmul, kernels/moe_gmm.py).
 
     ``backend_chooser`` (spec ``backend='auto'``, kernels/autotune.py)
     overrides the representation per pattern with the measured winner; a
@@ -422,24 +423,35 @@ def export_lm_sparse(params, cfg: ModelConfig, tile=(128, 128), *,
             occ = _tile_mask(w, tile)
         return bool(occ.mean() < 1.0)
 
-    def export_ffn(layer_params, scope, stacked):
-        # dense-MLP layers only ({'wi': {'w': ...}, ...}): MoE expert trees
-        # keep raw (E, d, f) arrays under the same names and have no packs
-        # route
-        if ("ffn" not in layer_params
-                or not isinstance(layer_params["ffn"].get("wi"), dict)):
-            return layer_params
-        fp = dict(layer_params["ffn"])
+    def export_mlp(mp, scope, stacked):
+        """The dense MLP tree ``mp`` with its block-sparse projections
+        packed."""
+        mp = dict(mp)
         for proj in _FFN_PROJS:
-            if proj not in fp:
+            if proj not in mp:
                 continue
-            w = _get_w(fp[proj])
+            w = _get_w(mp[proj])
             if not _divisible(w.shape, tile) or not _is_sparse(w, stacked):
                 continue
             data = _export_one(w, f"{scope}/{proj}", stacked, proj)
             if data is not None:
-                fp[proj] = _param_entry(
-                    data, layer_params["ffn"][proj]["w"].dtype)
+                mp[proj] = _param_entry(data, mp[proj]["w"].dtype)
+        return mp
+
+    def export_ffn(layer_params, scope, stacked):
+        # a dense MLP ({'wi': {'w': ...}, ...}) or an MoE layer's shared
+        # experts; routed experts keep raw (E, d, f) arrays, served by the
+        # grouped expert matmul
+        fp = layer_params.get("ffn")
+        if fp is None:
+            return layer_params
+        if isinstance(fp.get("wi"), dict):
+            fp = export_mlp(fp, scope, stacked)
+        elif "shared" in fp:
+            fp = dict(fp, shared=export_mlp(fp["shared"], f"{scope}/shared",
+                                            stacked))
+        else:
+            return layer_params
         out = dict(layer_params)
         out["ffn"] = fp
         return out

@@ -11,8 +11,8 @@ Three JSON/npz-safe codecs used by ``Servable.save`` / ``load_servable``
     npz arrays + JSON meta, deduplicated by pattern fingerprint so the
     cross-layer-union sharing (12 layer scopes -> 1 plan object) survives a
     round-trip and the loaded servable keeps one specialization per group;
-  * **config codec** -- ModelConfig (with nested LayerKind / SparsityConfig)
-    to plain dicts and back.
+  * **config codec** -- ModelConfig (with nested LayerKind, SparsityConfig
+    and YarnRope) to plain dicts and back.
 """
 from __future__ import annotations
 
@@ -22,7 +22,7 @@ from typing import Dict, List, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import LayerKind, ModelConfig
+from repro.configs.base import LayerKind, ModelConfig, YarnRope
 from repro.core.pattern_reuse import PatternRegistry
 from repro.core.sparsity import SparsityConfig
 from repro.kernels.autotune import BackendChoice, MaskedPack
@@ -363,4 +363,6 @@ def config_from_dict(d: dict) -> ModelConfig:
         sp["block_shape"] = tuple(sp["block_shape"])
         sp["targets"] = tuple(sp["targets"])
         d["sparsity"] = SparsityConfig(**sp)
+    if d.get("rope_yarn"):
+        d["rope_yarn"] = YarnRope(**d["rope_yarn"])
     return ModelConfig(**d)

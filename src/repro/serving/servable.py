@@ -246,8 +246,9 @@ class Servable:
 
             def step(p, c, t, s):
                 with decode_kernel_override(kind):
-                    return model_api.decode_step(p, c, cfg, t, s,
-                                                 packs=packs)
+                    logits, c, _ = model_api.decode_step(p, c, cfg, t, s,
+                                                         packs=packs)
+                    return logits, c
             self._decode_fn = jax.jit(step)
         return self._decode_fn(self.params, cache, token, pos)
 
@@ -300,12 +301,13 @@ class Servable:
         (jit retraces per (max_slots, cache) shape and per static
         (temperature, top_k); executables persist across engine
         instances). Returns ``(sampled_tokens (B,), ok (B,) bool, logits,
-        cache)`` -- sampling (greedy argmax, or temperature/top-k with the
-        slot+position-keyed PRNG of models/sampling.py) runs on device so
-        the hot loop only moves B int32s + B bools to host; ``ok`` is the
-        per-slot non-finite guard (False = that slot's logits row went
-        NaN/inf and the engine must quarantine it), and the full logits
-        land on host only when an engine collects them. The cache argument
+        cache, moe_rows)`` (``models.api.decode_step``) -- sampling (greedy
+        argmax, or temperature/top-k with the slot+position-keyed PRNG of
+        models/sampling.py) runs on device so the hot loop only moves B
+        int32s + B bools to host; ``ok`` is the per-slot non-finite guard
+        (False = that slot's logits row went NaN/inf and the engine must
+        quarantine it), and the full logits land on host only when an
+        engine collects them. The cache argument
         is DONATED -- engine hot-loop use only; :meth:`decode_step` is the
         non-donating API.
 
@@ -319,16 +321,16 @@ class Servable:
 
             def decode(p, c, t, s, key, temperature, top_k):
                 with decode_kernel_override(kind):
-                    logits, c = model_api.decode_step(p, c, cfg, t, s,
-                                                      packs=packs)
+                    logits, c, moe_rows = model_api.decode_step(
+                        p, c, cfg, t, s, packs=packs)
                 rows = logits[:, 0, :]
                 ok = jnp.isfinite(rows).all(axis=-1)
                 nxt = sample_tokens(rows, key, s,
                                     temperature=temperature, top_k=top_k)
-                return nxt, ok, logits, c
+                return nxt, ok, logits, c, moe_rows
 
             kw = {} if cache_shardings is None else \
-                {"out_shardings": (None, None, None, cache_shardings)}
+                {"out_shardings": (None, None, None, cache_shardings, None)}
             fn = jax.jit(decode, donate_argnums=(1,),
                          static_argnums=(5, 6), **kw)
             if cache_shardings is not None:
@@ -361,7 +363,8 @@ class Servable:
                 kw["out_shardings"] = (
                     None, None, {"token": None, "pos": None,
                                  "remaining": None, "failed": None,
-                                 "cache": cache_shardings})
+                                 "cache": cache_shardings,
+                                 "moe_rows": None})
             fn = jax.jit(fused, donate_argnums=(1,),
                          static_argnums=(7, 8, 9), **kw)
             if cache_shardings is not None:
@@ -372,8 +375,9 @@ class Servable:
     def _engine_prefill_fn(self):
         """Jitted prompt prefill shared by every engine of this servable.
         Uniform signature ``(params, cache1, tokens (bucket,), pos_seq
-        (bucket,), length) -> (cache1, logits (bucket, V))``; one trace per
-        bucket length serves every admission (``length`` is traced).
+        (bucket,), length) -> (cache1, logits (bucket, V), moe_rows)``
+        (``models.api.decode_step``); one trace per bucket length serves
+        every admission (``length`` is traced).
 
         lm-family models run the ONE-PASS forward prefill
         (``models.api.prefill_cache``): the whole prompt streams the weights
@@ -387,15 +391,18 @@ class Servable:
                 def prefill(params, cache, tokens, pos_seq, length):
                     def step(c, tp):
                         tok, p = tp
-                        logits, c = model_api.decode_step(
+                        logits, c, _ = model_api.decode_step(
                             params, c, cfg, tok[None, None], p[None])
                         return c, logits[0, 0]
-                    return jax.lax.scan(step, cache, (tokens, pos_seq))
+                    cache, logits = jax.lax.scan(step, cache,
+                                                 (tokens, pos_seq))
+                    return cache, logits, jnp.zeros((cfg.n_held,),
+                                                    jnp.int32)
             else:
                 def prefill(params, cache, tokens, pos_seq, length):
-                    logits, cache = model_api.prefill_cache(
+                    logits, cache, rows = model_api.prefill_cache(
                         params, cache, cfg, tokens[None], length, packs=packs)
-                    return cache, logits[0]
+                    return cache, logits[0], rows
 
             self._engine_prefill = jax.jit(prefill)
         return self._engine_prefill
@@ -449,7 +456,7 @@ class Servable:
     def suffix_prefill_fn(self, cache_shardings=None):
         """Jitted suffix/chunk prefill over the BATCHED engine cache:
         ``suffix_prefill(params, cache, tokens (S,), slot, start, length)
-        -> (cache, logits (S, V))``. One trace per bucketed chunk length S
+        -> (cache, logits (S, V), moe_rows)``. One trace per bucketed chunk length S
         serves every chunk (``slot``/``start``/``length`` are traced). The
         cache is DONATED, like the decode and write-slot jits: at serving
         scale the batched cache is tens of MB and an un-donated copy per
@@ -468,12 +475,12 @@ class Servable:
 
         def build():
             def suffix(params, cache, tokens, slot, start, length):
-                logits, cache = model_api.prefill_suffix(
+                logits, cache, rows = model_api.prefill_suffix(
                     params, cache, cfg, tokens[None], slot, start, length,
                     packs=packs)
-                return cache, logits[0]
+                return cache, logits[0], rows
             skw = {} if cache_shardings is None else \
-                {"out_shardings": (cache_shardings, None)}
+                {"out_shardings": (cache_shardings, None, None)}
             return jax.jit(suffix, donate_argnums=(1,), **skw)
 
         if cache_shardings is None:
@@ -501,7 +508,7 @@ class Servable:
           length)`` -- prefill only the uncached prompt suffix against a
           shared resident prefix; :meth:`suffix_prefill_fn`, shared with
           the dense chunked-prefill path. Returns ``(cache, logits
-          (S, V))``.
+          (S, V), moe_rows)``.
 
         Cached like :meth:`engine_fns`: unsharded engines share the
         Servable-held trio, mesh engines share per cache-sharding tree."""

@@ -63,6 +63,9 @@ CFGS = {
     # RG-LRU recurrence with the slot's carried state + conv history
     "hybrid-ssm": lambda: get_config("mamba2_780m", smoke=True),
     "hybrid-rglru": lambda: get_config("recurrentgemma_9b", smoke=True),
+    # MLA under YaRN with drop-free MoE layers: a token's routed experts do
+    # not depend on the chunk it arrives in
+    "moe-v2-lite": lambda: get_config("deepseek_v2_lite_16b", smoke=True),
 }
 
 SCHED = SchedSpec(max_chunk=8, token_budget=16)
@@ -112,7 +115,7 @@ def test_chunked_equals_oneshot_dense(family):
     eng.verify_invariants()
 
 
-@pytest.mark.parametrize("family", ["attn", "mla"])
+@pytest.mark.parametrize("family", ["attn", "mla", "moe-v2-lite"])
 def test_chunked_equals_oneshot_paged(family):
     cfg = CFGS[family]()
     sv = _servable(cfg, kv_layout="paged", kv_page_size=8)
@@ -213,15 +216,16 @@ def test_budget_prevents_head_of_line_blocking():
 
 
 def test_chunking_gate_falls_back_for_moe():
-    """MoE routing is batch-global: the engine must silently fall back to
-    one-shot admission (sched's other knobs stay live)."""
+    """The MoE layer drops nothing, so no MoE config falls back to one-shot
+    admission any more: it chunks, and its tokens equal one-shot's."""
     cfg = get_config("qwen3_moe_235b_a22b", smoke=True)
     sv = _servable(cfg)
-    eng = sv.engine(max_slots=2, cache_len=64, sched=SCHED)
-    assert not eng._chunking
-    r = eng.submit(list(range(1, 12)), max_new_tokens=4)
-    eng.run()
-    assert r.status == "done" and eng.stats.prefill_chunks == 0
+    prompts = _prompts(cfg)
+    _, base = _drain(sv, prompts)
+    eng, chunked = _drain(sv, prompts, sched=SCHED)
+    assert eng._chunking and eng.stats.prefill_chunks > len(prompts)
+    _assert_parity(base, chunked, "moe")
+    eng.verify_invariants()
 
 
 def test_sched_spec_roundtrip_via_serving_spec():

@@ -16,7 +16,6 @@ Multi-device tests need a forced host-platform mesh:
 single-device run these tests skip). The pure-kernel ShardedPlan tests and
 spec validation run everywhere.
 """
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -291,8 +290,7 @@ def test_family_engine_parity_tp8(arch):
 
 @needs8
 def test_family_engine_parity_moe_tp8():
-    cfg = dataclasses.replace(get_config("deepseek_v2_lite_16b", smoke=True),
-                              capacity_factor=8.0)
+    cfg = get_config("deepseek_v2_lite_16b", smoke=True)
     params = init_model(jax.random.PRNGKey(1), cfg)
     spec = dict(tile=(16, 16), sparsity=0.5, prune="oneshot",
                 targets=ATTN_TARGETS)

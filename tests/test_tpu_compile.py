@@ -28,6 +28,7 @@ from repro.kernels.bsr_matmul import pack_bsr, plan_dds, plan_dds_q
 from repro.kernels.exec_plan import (build_plan, pack_plan_data,
                                      plan_kernel_sequence, quant_granularity)
 from repro.kernels.flash_decode import flash_decode, paged_flash_decode
+from repro.kernels.moe_gmm import _moe_gmm_call, _ffn_tile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -191,6 +192,80 @@ def test_paged_decode_window_moves_no_pool(one_chip, monkeypatch):
     assert not found, found
 
 
+#: DeepSeek-V2-Lite's held experts: 16 x (2048 -> 1408 -> 2048) SwiGLU,
+#: stacked over its 26 MoE layers
+MOE_WIDTHS = (16, 2048, 1408)
+MOE_LAYERS = 26
+
+
+def _moe_gmm(one_chip, rows=624, tm=16):
+    """The grouped expert kernel at published widths, as the layer scan
+    calls it (the experts of every MoE layer stacked, read at a layer
+    index): rows of a 64-slot decode step (384 assignments padded per
+    expert to 16-row tiles)."""
+    h, d, f = MOE_WIDTHS
+    dt = jnp.bfloat16
+    s = lambda shape, dtype=dt: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    tf = _ffn_tile(d, f, 2)
+    return _compile(lambda g, u, layer, x, wi, wg, wo: _moe_gmm_call(
+        g, u, layer, x, wi, wg, wo, tm=tm, tf=tf, interpret=False),
+        s((rows // tm,), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32),
+        s((rows, d)), s((MOE_LAYERS, h, d, f)), s((MOE_LAYERS, h, d, f)),
+        s((MOE_LAYERS, h, f, d)))
+
+
+@pytest.mark.parametrize("rows,tm", [(624, 16), (8176, 128)])
+def test_moe_gmm_compiles(one_chip, rows, tm):
+    """A decode step's rows (tiles of 16) and a 1024-token prefill's
+    (tiles of 128), the whole FFN width in one block."""
+    assert _ffn_tile(*MOE_WIDTHS[1:], 2) == MOE_WIDTHS[2]
+    _moe_gmm(one_chip, rows, tm)
+
+
+def test_moe_decode_window_copies_no_experts(one_chip, monkeypatch):
+    """The fused decode window of a small MoE model, its routed experts
+    through the compiled ``moe_gmm``: the optimized TPU HLO holds no copy,
+    slice or reshape of one layer's experts or of the stacked experts (the
+    layer scan passes them whole, read at the layer index)."""
+    from repro.configs.base import LayerKind, ModelConfig
+    from repro.kernels import moe_gmm as gmm_mod
+    from repro.models import api as model_api
+    from repro.models import moe as moe_mod
+    from test_kv_pool_in_place import pool_moves_in_hlo
+    monkeypatch.setattr(moe_mod, "moe_gmm", lambda *a, **k: gmm_mod.moe_gmm(
+        *a, kernel=True, interpret=False, **k))
+    cfg = ModelConfig(
+        arch="moe-tpu", family="moe", n_layers=3, d_model=1024, n_heads=8,
+        n_kv_heads=8, head_dim=128, d_ff=1024, d_ff_expert=1408,
+        vocab_size=512, n_experts=32, top_k=6, experts_held=8,
+        pattern=(LayerKind("attn", "moe"),), dtype="bfloat16")
+    slots, cache_len = 8, 256
+    params = jax.eval_shape(lambda: model_api.init_model(
+        jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: model_api.init_cache(
+        None, cfg, slots, cache_len))
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    experts = {x.shape for p, x in jax.tree_util.tree_leaves_with_path(params)
+               if str(getattr(p[-1], "key", "")) in ("wi", "wg", "wo")
+               and len(x.shape) == 4}
+    assert experts == {(3, 8, 1024, 1408), (3, 8, 1408, 1024)}
+    # one layer's experts, alone or as a stack of one
+    shapes = experts | {s[1:] for s in experts} | {(1,) + s[1:]
+                                                    for s in experts}
+    hlo = jax.jit(lambda p, c, t, s: model_api.decode_many(
+        p, c, cfg, t, s, 4), donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        *on_chip((jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                  jax.ShapeDtypeStruct((slots,), jnp.int32)))
+    ).compile().as_text()
+    assert "_moe_gmm_call" in hlo
+    found = pool_moves_in_hlo(hlo, shapes)
+    assert not found, found
+
+
 def _needles(family):
     """``KERNELS`` of ``bench/families/<family>.py``: per roofline, the
     string it looks for in a device op's own name."""
@@ -214,12 +289,14 @@ def _custom_call_names(compiled):
     ("bert", "sparse_matmul", _plan_dds),
     ("lm", "sparse_matmul", _plan_dds),
     ("lm", "decode_attn", _paged_flash_decode),
+    ("mla_moe", "sparse_matmul", _plan_dds),
+    ("mla_moe", "moe_gmm", _moe_gmm),
 ])
 def test_kernel_names_carry_the_benchmark_needles(one_chip, family, kernel,
                                                   compile_fn):
-    """A rename of ``_plan_dds_call`` or ``_paged_flash_call`` would leave
-    the rooflines nothing to read: the benchmark finds each kernel by a
-    string its op name contains."""
+    """A rename of ``_plan_dds_call``, ``_paged_flash_call`` or
+    ``_moe_gmm_call`` would leave the rooflines nothing to read: the
+    benchmark finds each kernel by a string its op name contains."""
     needle = _needles(family)[kernel]
     names = _custom_call_names(compile_fn(one_chip))
     assert any(needle in n for n in names), (needle, names)
