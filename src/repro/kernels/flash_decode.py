@@ -7,29 +7,45 @@ round-trips over a tensor that grows with context length. The kernels here
 run the flash-style online softmax on chip instead:
 
   * ``flash_decode``        -- dense (B, T, Hkv, D) slot caches. The grid is
-      (B, Hkv, num_split): the KV axis is cut into ``num_split`` chunks
-      (split-K) and each grid step folds one chunk into running
-      (max, sum, acc) VMEM scratch; TPU grids iterate sequentially, so the
-      scratch IS the split-K reduction and the normalized output is written
-      by the last split -- no inter-step HBM traffic.
+      (B, Hkv / hb, num_split): the KV axis is cut into ``num_split``
+      chunks (split-K) and each grid step folds one chunk of ``hb`` KV
+      heads into running (max, sum, acc) VMEM scratch; TPU grids iterate
+      sequentially, so the scratch IS the split-K reduction and the
+      normalized output is written by the last split -- no inter-step HBM
+      traffic.
   * ``paged_flash_decode``  -- page-pool caches (serving/paging.py). One KV
       split == one page: the scalar-prefetched page table drives the
       BlockSpec index map, so each grid step DMAs its page from the pool
-      *in place*. This kills the dense-view reassembly tax: the PR-7 paged
-      decode gathered a (B, T, Hkv, D) contiguous view per step per layer
-      before attending; here no view is ever materialized. The pools are
-      stored (.., page_size, Hkv*D), the layout the blocks are cut from,
-      and the layer scan passes them stacked with the layer index, so
-      nothing copies or relayouts a pool on the way in.
+      *in place* -- no dense (B, T, Hkv, D) view is ever gathered. The
+      pools are stored (.., page_size, Hkv*D), heads-major, so one page of
+      ``hb`` heads is one contiguous (page_size, hb*D) block; the layer
+      scan passes them stacked with the layer index, so nothing copies or
+      relayouts a pool on the way in. A page that holds no position the
+      slot can see -- past its position, unmapped, or any page of an
+      inactive slot -- costs neither a DMA nor the fold: its index map
+      repeats the slot's last page (an unchanged block index issues no
+      copy) and ``pl.when`` skips the body. The layout is linear
+      (``pos_map[b, j]`` is -1 or j), so such a page is fully masked and
+      skipping it is exact.
+
+``hb`` (``heads_per_block``) is a function of the shape alone: the largest
+divisor of Hkv whose double-buffered K and V blocks fit ``KV_BLOCK_BUDGET``
+(8 MiB, half the v5e's scoped VMEM), among lane-aligned slices (hb*D a
+multiple of 128) or the whole row. The DeepSeek-LLM-7B's 32 heads of 128 at
+pages of 128 take all 32 (1 MB of K and of V a step), 8 x 128 GQA takes 8,
+gemma3's 4 x 256 at pages of 16 takes 4. A step walked once per head and
+page costs a fixed ~0.5 us on a v5e whatever it reads; one per page of all
+heads pays for bytes instead.
 
 Masking follows decode_attention exactly: ``pos_map`` holds the absolute
 position stored in each cache slot (-1 = empty), queries see positions
 ``0 <= kvp <= pos`` (minus the window cut for ring caches). Because masked
 lanes are zeroed *before* the exp (never ``exp(-inf - -inf)``), a fully
 masked split -- an unmapped page, an empty ring region, an inactive slot --
-contributes exact zeros, which is what makes ``paged_flash_decode``
-bit-exact vs ``flash_decode`` over the gathered dense view with matching
-split boundaries (tests/test_pallas_serving.py).
+contributes exact zeros, which is what makes ``paged_flash_decode`` (which
+skips such a page) bit-exact vs ``flash_decode`` over the gathered dense
+view (which folds it) with matching split boundaries
+(tests/test_pallas_serving.py).
 
 Kernel selection: ``resolved_decode_kernel()`` reads an explicit
 ``decode_kernel_override`` context (set by Servable from the ServingSpec at
@@ -60,9 +76,10 @@ _OVERRIDE: list = []
 
 def flash_decode_aligned(hkv: int, d: int, chunk: int = 8) -> bool:
     """Whether the compiled kernels can take this decode shape: each grid
-    step streams one KV head's (chunk, D) slice of the (.., T, Hkv*D) cache
-    view, which the TPU (8, 128) tiling accepts when D is a lane multiple
-    (or spans the whole row, Hkv == 1) and the chunk a sublane multiple."""
+    step streams ``heads_per_block`` KV heads' (chunk, hb*D) slice of the
+    (.., T, Hkv*D) cache view, which the TPU (8, 128) tiling accepts when D
+    is a lane multiple (or spans the whole row, Hkv == 1) and the chunk a
+    sublane multiple."""
     return (hkv == 1 or d % 128 == 0) and chunk % 8 == 0
 
 
@@ -116,15 +133,65 @@ def default_kv_split(t: int) -> int:
 # shared online-softmax split step
 # --------------------------------------------------------------------------
 
-def _flash_decode_kernel(*refs, n_prefetch, num_split, window, scale):
-    """One KV split: fold (k, v, kvp) into running (m, l, acc) scratch.
+#: VMEM for one grid step's K and V blocks, double-buffered: half the v5e's
+#: 16 MiB scoped default, leaving the rest to the body's temporaries
+KV_BLOCK_BUDGET = 8 << 20
+
+
+def heads_per_block(hkv: int, d: int, chunk: int, itemsize: int) -> int:
+    """KV heads one grid step covers: the largest divisor ``hb`` of ``hkv``
+    whose K and V blocks of ``chunk`` positions, double-buffered
+    (4 * chunk * hb * d * itemsize bytes), fit ``KV_BLOCK_BUDGET``, among
+    those the TPU tiling takes -- a lane-aligned slice (hb * d a multiple
+    of 128) or the whole row (hb == hkv). If none fits, the smallest
+    legal block."""
+    legal = [hb for hb in range(1, hkv + 1) if hkv % hb == 0
+             and (hb == hkv or hb * d % 128 == 0)]
+    fits = [hb for hb in legal
+            if 4 * chunk * hb * d * itemsize <= KV_BLOCK_BUDGET]
+    return max(fits) if fits else min(legal)
+
+
+def _fold_heads(q, k, v, ok, m_ref, l_ref, acc_ref, *, hb, scale):
+    """Fold one split of ``hb`` KV heads into the running (m, l, acc)
+    scratch: head h is query rows [h*G, (h+1)*G) of q and columns
+    [h*D, (h+1)*D) of k and v, static lane-aligned slices, each folded
+    with the one-head online softmax in its own order of operations."""
+    g, d = q.shape[0] // hb, q.shape[1]
+    for h in range(hb):
+        r, c = slice(h * g, (h + 1) * g), slice(h * d, (h + 1) * d)
+        s_ = jax.lax.dot_general(
+            q[r], k[:, c], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (G, ck)
+        s_ = jnp.where(ok, s_, NEG_INF)
+        m_prev = m_ref[r, :1]                             # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+        # masked lanes zero BEFORE exp: a fully masked split keeps m at
+        # NEG_INF and must contribute exactly nothing
+        # (exp(NEG_INF - NEG_INF) == 1)
+        p = jnp.where(ok, jnp.exp(s_ - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)                    # (G, 1)
+        l_new = l_ref[r, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[r, :] = acc_ref[r, :] * corr + jax.lax.dot_general(
+            p, v[:, c], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (G, D)
+        m_ref[r, :] = jnp.broadcast_to(m_new, (g, m_ref.shape[1]))
+        l_ref[r, :] = jnp.broadcast_to(l_new, (g, l_ref.shape[1]))
+
+
+def _flash_decode_kernel(*refs, n_prefetch, num_split, window, scale, hb,
+                         live=None):
+    """One KV split of ``hb`` KV heads: fold (k, v, kvp) into running
+    (m, l, acc) scratch.
 
     refs = (*scalar_prefetch, q, k, v, kvp, o, m_scratch, l_scratch, acc).
     prefetch[0] is the per-slot position vector; the paged variant adds the
-    flattened page table and the layer index (consumed only by the BlockSpec
-    index maps). k/v
-    blocks are one KV head's (1, ck, D) slice of the (.., ck, Hkv*D) cache
-    view, kvp one split's (1, 1, ck) row of positions.
+    flattened page table and the layer index. q is the block's
+    (hb * G, D) query rows, head-major; k/v blocks are ``hb`` KV heads'
+    (ck, hb * D) slice of the (.., ck, Hkv * D) cache rows, kvp one
+    split's (1, 1, ck) row of positions. ``live(prefetch, b, si)``, when
+    given, gates the fold: a split it rejects holds no visible position
+    and is skipped.
     """
     pos_ref = refs[0]
     q_ref, k_ref, v_ref, kvp_ref, o_ref, m_ref, l_ref, acc_ref = \
@@ -138,35 +205,30 @@ def _flash_decode_kernel(*refs, n_prefetch, num_split, window, scale):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                   # (G, D)
-    k = k_ref[0]                                      # (ck, D)
-    v = v_ref[0]
-    s_ = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # (G, ck)
-    kvp = kvp_ref[0]                                  # (1, ck)
-    pos = pos_ref[b]
-    ok = (kvp >= 0) & (kvp <= pos)
-    if window > 0:
-        ok &= kvp > pos - window
-    s_ = jnp.where(ok, s_, NEG_INF)
-    m_prev = m_ref[:, :1]                             # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-    # masked lanes zero BEFORE exp: a fully masked split keeps m at NEG_INF
-    # and must contribute exactly nothing (exp(NEG_INF - NEG_INF) == 1)
-    p = jnp.where(ok, jnp.exp(s_ - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)                    # (G, 1)
-    l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (G, D)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    def fold():
+        kvp = kvp_ref[0]                              # (1, ck)
+        pos = pos_ref[b]
+        ok = (kvp >= 0) & (kvp <= pos)
+        if window > 0:
+            ok &= kvp > pos - window
+        _fold_heads(q_ref[0, 0], k_ref[0], v_ref[0], ok, m_ref, l_ref,
+                    acc_ref, hb=hb, scale=scale)
+
+    if live is None:
+        fold()
+    else:
+        pl.when(live(refs[:n_prefetch], b, si))(fold)
 
     @pl.when(si == num_split - 1)
     def _():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _scratch(rows, d):
+    return [pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32)]
 
 
 @functools.partial(jax.jit,
@@ -175,35 +237,38 @@ def _flash_decode_call(q4, k, v, kvp, pos, *, window, num_split, interpret):
     b, hkv, g, d = q4.shape
     t = k.shape[1]
     ck = t // num_split
-    grid = (b, hkv, num_split)
-    # (B, T, Hkv, D) -> (B, T, Hkv*D) and positions -> (B*S, 1, ck) are free
-    # reshapes that give every block TPU-tileable trailing dims
+    hb = heads_per_block(hkv, d, ck, k.dtype.itemsize)
+    grid = (b, hkv // hb, num_split)
+    # (B, T, Hkv, D) -> (B, T, Hkv*D), queries -> (B, Hkv/hb, hb*G, D) and
+    # positions -> (B*S, 1, ck) are free reshapes that give every block
+    # TPU-tileable trailing dims
     k = k.reshape(b, t, hkv * d)
     v = v.reshape(b, t, hkv * d)
+    q4 = q4.reshape(b, hkv // hb, hb * g, d)
     kvp = kvp.reshape(b * num_split, 1, ck)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_flash_decode_kernel, n_prefetch=1,
                           num_split=num_split, window=window,
-                          scale=d ** -0.5),
+                          scale=d ** -0.5, hb=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, d), lambda b, h, s, pos: (b, h, 0, 0)),
-                pl.BlockSpec((1, ck, d), lambda b, h, s, pos: (b, s, h)),
-                pl.BlockSpec((1, ck, d), lambda b, h, s, pos: (b, s, h)),
+                pl.BlockSpec((1, 1, hb * g, d),
+                             lambda b, h, s, pos: (b, h, 0, 0)),
+                pl.BlockSpec((1, ck, hb * d), lambda b, h, s, pos: (b, s, h)),
+                pl.BlockSpec((1, ck, hb * d), lambda b, h, s, pos: (b, s, h)),
                 pl.BlockSpec((1, 1, ck),
                              lambda b, h, s, pos: (b * num_split + s, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, d),
+            out_specs=pl.BlockSpec((1, 1, hb * g, d),
                                    lambda b, h, s, pos: (b, h, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((g, 128), jnp.float32),
-                            pltpu.VMEM((g, 128), jnp.float32),
-                            pltpu.VMEM((g, d), jnp.float32)],
+            scratch_shapes=_scratch(hb * g, d),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q4.dtype),
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         interpret=interpret,
     )(pos, q4, k, v, kvp)
+    return out.reshape(b, hkv, g, d)
 
 
 def flash_decode(q, k_cache, v_cache, kv_positions, pos, *, window=0,
@@ -238,7 +303,8 @@ def flash_decode(q, k_cache, v_cache, kv_positions, pos, *, window=0,
 
 
 # --------------------------------------------------------------------------
-# paged variant: one split == one page, gathered in place via the table
+# paged variant: one split == one page of hb heads, read in place via the
+# table; pages past a slot's position cost no DMA and no fold
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("window", "npg", "interpret"))
@@ -246,42 +312,56 @@ def _paged_flash_call(q4, k_pages, v_pages, pt_flat, pm, pos, layer, *,
                       window, npg, interpret):
     b, hkv, g, d = q4.shape
     ps = k_pages.shape[2]
-    grid = (b, hkv, npg)
+    hb = heads_per_block(hkv, d, ps, k_pages.dtype.itemsize)
+    grid = (b, hkv // hb, npg)
     # the stacked pools (P, pages, ps, Hkv*D) are read where they lie: one
-    # block is one layer's page of one KV head; pos_map -> (B*NP, 1, ps)
-    # (a free reshape) so one page is one (1, 1, ps) block
+    # block is one layer's page of hb KV heads, (ps, hb*D) contiguous
+    # rows; pos_map -> (B*NP, 1, ps) (a free reshape) so one page is one
+    # (1, 1, ps) block
+    q4 = q4.reshape(b, hkv // hb, hb * g, d)
     pm = pm.reshape(b * npg, 1, ps)
 
-    def page_map(b, h, s, pos, pt, layer):
-        # unmapped (-1) pages clip to page 0; their pos_map slots are -1 so
-        # every lane of the split is masked before the exp
-        return (layer[0], jnp.maximum(pt[b * npg + s], 0), 0, h)
+    def live(b, s, pos, pt):
+        # page s of slot b holds a position the slot can see: mapped, and
+        # starting at or before the slot's position (the paged layout is
+        # linear, pos_map[b, j] is -1 or j, so every later page is masked)
+        return (s * ps <= pos[b]) & (pt[b * npg + s] >= 0)
 
-    return pl.pallas_call(
+    def split(b, s, pos, pt):
+        # a dead page repeats the slot's last page: the block index does
+        # not change, so the pipeline issues no DMA for it
+        last = jnp.minimum(jnp.maximum(pos[b], 0) // ps, npg - 1)
+        return jnp.where(live(b, s, pos, pt), s, last)
+
+    def page_map(b, h, s, pos, pt, layer):
+        page = jnp.maximum(pt[b * npg + split(b, s, pos, pt)], 0)
+        return (layer[0], page, 0, h)
+
+    out = pl.pallas_call(
         functools.partial(_flash_decode_kernel, n_prefetch=3, num_split=npg,
-                          window=window, scale=d ** -0.5),
+                          window=window, scale=d ** -0.5, hb=hb,
+                          live=lambda pf, b, s: live(b, s, pf[0], pf[1])),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, d),
+                pl.BlockSpec((1, 1, hb * g, d),
                              lambda b, h, s, pos, pt, layer: (b, h, 0, 0)),
-                pl.BlockSpec((pl.squeezed, 1, ps, d), page_map),
-                pl.BlockSpec((pl.squeezed, 1, ps, d), page_map),
+                pl.BlockSpec((pl.squeezed, 1, ps, hb * d), page_map),
+                pl.BlockSpec((pl.squeezed, 1, ps, hb * d), page_map),
                 pl.BlockSpec((1, 1, ps),
                              lambda b, h, s, pos, pt, layer:
-                             (b * npg + s, 0, 0)),
+                             (b * npg + split(b, s, pos, pt), 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, d),
+            out_specs=pl.BlockSpec((1, 1, hb * g, d),
                                    lambda b, h, s, pos, pt, layer:
                                    (b, h, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((g, 128), jnp.float32),
-                            pltpu.VMEM((g, 128), jnp.float32),
-                            pltpu.VMEM((g, d), jnp.float32)],
+            scratch_shapes=_scratch(hb * g, d),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q4.dtype),
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         interpret=interpret,
     )(pos, pt_flat, layer, q4, k_pages, v_pages, pm)
+    return out.reshape(b, hkv, g, d)
 
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, pos_map, pos, *,

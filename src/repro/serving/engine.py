@@ -223,6 +223,12 @@ class EngineStats:
     # could have taken them -- set also on the admit and window spans
     moe_rows: int = 0
     moe_expert_slots: int = 0
+    # paged decode windows: KV pages up to each decoding slot's position
+    # and the pages its table spans, per step -- the share of the paged
+    # flash kernel's grid steps that read and fold a page (set also on the
+    # window span, from the host's positions at the window's start)
+    kv_pages_live: int = 0
+    kv_pages_walked: int = 0
 
     @property
     def mean_occupancy(self) -> float:
@@ -248,7 +254,9 @@ class EngineStats:
                 "decode_s": round(self.decode_s, 4),
                 "sync_s": round(self.sync_s, 4),
                 "moe_rows": self.moe_rows,
-                "moe_expert_slots": self.moe_expert_slots}
+                "moe_expert_slots": self.moe_expert_slots,
+                "kv_pages_live": self.kv_pages_live,
+                "kv_pages_walked": self.kv_pages_walked}
 
 
 class ServingEngine:
@@ -1496,7 +1504,7 @@ class ServingEngine:
         on host here). ``decoding`` is the slot set this window actually
         decodes -- mid-prefill slots are skipped at the drain (their rows
         are device no-ops and must not be quarantined or emitted)."""
-        with self._window_span(1) as sp:
+        with self._window_span(1, decoding) as sp:
             self.stats.steps += 1
             self.stats.windows += 1
             self.stats.occupancy_sum += len(decoding)
@@ -1533,12 +1541,22 @@ class ServingEngine:
         self.stats.moe_expert_slots += slots
         sp.set(moe_rows=n, moe_expert_slots=slots)
 
-    def _window_span(self, k: int):
+    def _window_span(self, k: int, decoding: List[int]):
         """The ``repro.engine.window`` span of one decode window of ``k``
         steps over ``max_slots`` rows, timed into ``stats.decode_s``;
-        ``tokens``, set at exit, the tokens it emitted."""
+        ``tokens``, set at exit, the tokens it emitted. A paged engine
+        adds ``kv_pages_live`` (the pages up to each ``decoding`` slot's
+        position) and ``kv_pages_walked`` (the table's width per decoding
+        slot), each times ``k``, to the span and to ``stats``."""
+        pages = {}
+        if self.kv_layout == "paged":
+            pages = {"kv_pages_live": k * sum(
+                int(self._pos[s]) // self.kv_page_size + 1 for s in decoding),
+                "kv_pages_walked": k * len(decoding) * self._table_width}
+            for name, n in pages.items():
+                setattr(self.stats, name, getattr(self.stats, name) + n)
         return timed(self.stats, "decode_s", "repro.engine.window", k=k,
-                     slots=self.max_slots, tokens=0)
+                     slots=self.max_slots, tokens=0, **pages)
 
     def _step_fused(self, k: int, decoding: List[int]) -> None:
         """The fused hot loop: K decode steps inside one jitted scan
@@ -1549,7 +1567,7 @@ class ServingEngine:
         ``max_new_tokens``; slots that hit EOS (or their budget, or
         non-finite logits) mid-window deactivate themselves on device and
         ride along as no-ops until the sync."""
-        with self._window_span(k) as sp:
+        with self._window_span(k, decoding) as sp:
             self.stats.steps += k
             self.stats.windows += 1
             toks, valid, state = self._decode_many(

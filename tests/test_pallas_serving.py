@@ -32,7 +32,7 @@ from repro.kernels.exec_plan import (PlanChoice, build_plan, pack_plan_data,
                                      unpack_plan_data)
 from repro.kernels.flash_decode import (decode_kernel_override,
                                         default_kv_split, flash_decode,
-                                        paged_flash_decode,
+                                        heads_per_block, paged_flash_decode,
                                         resolved_decode_kernel)
 from repro.models.attention import decode_attention
 from repro.models.common import paged_view
@@ -370,6 +370,87 @@ def test_paged_flash_decode_ignores_stale_pages():
     vp2 = vp.at[0].set(1e6).at[5].set(-1e6)
     out = paged_flash_decode(q, kp2, vp2, table, pos_map, pos)
     assert np.array_equal(np.asarray(base), np.asarray(out))
+
+
+def _paged_slots_case(rng, hkv, g, d):
+    """Four slots over pages of 8: slot 0's pages after its position are
+    unmapped, slot 1's table has a hole (its pos_map -1 there) before two
+    live pages, slot 2 is inactive (pos -1) though it holds pages, slot 3
+    sits on the first position of its last mapped page."""
+    b, npg, ps = 4, 4, 8
+    n_pages = b * npg + 2
+    kp = jnp.asarray(rng.randn(n_pages, ps, hkv * d).astype(np.float32))
+    vp = jnp.asarray(rng.randn(n_pages, ps, hkv * d).astype(np.float32))
+    table = jnp.asarray([[4, 11, -1, -1], [2, -1, 7, 16],
+                         [9, 3, -1, -1], [5, 0, 13, -1]], jnp.int32)
+    pos = jnp.asarray([ps + 5, 3 * ps + 2, -1, 2 * ps], jnp.int32)
+    pos_map = np.full((b, npg * ps), -1, np.int32)
+    for i, (row, p) in enumerate(zip(np.asarray(table), np.asarray(pos))):
+        for j in range(p + 1):
+            if row[j // ps] >= 0:
+                pos_map[i, j] = j
+    q = jnp.asarray(rng.randn(b, 1, hkv * g, d).astype(np.float32))
+    return q, kp, vp, table, jnp.asarray(pos_map), pos
+
+
+@pytest.mark.parametrize("hkv,g,d", [(2, 1, 16), (2, 2, 16), (4, 1, 32)])
+def test_paged_flash_decode_skips_dead_pages(hkv, g, d):
+    """Pages past a slot's position, holes and inactive slots read nothing
+    and fold nothing: the output still matches the XLA reference over the
+    dense view, and an inactive slot's rows are exact zeros."""
+    q, kp, vp, table, pos_map, pos = _paged_slots_case(
+        np.random.RandomState(13), hkv, g, d)
+    out = np.asarray(paged_flash_decode(q, kp, vp, table, pos_map, pos))
+    shape = (q.shape[0], pos_map.shape[1], hkv, d)
+    ref = np.asarray(decode_attention(
+        q, paged_view(kp, table, pos_map).reshape(shape),
+        paged_view(vp, table, pos_map).reshape(shape), pos_map, pos))
+    active = np.asarray(pos) >= 0
+    np.testing.assert_allclose(out[active], ref[active], atol=1e-5)
+    assert np.all(out[~active] == 0.0)
+
+
+def test_paged_flash_decode_head_blocks(monkeypatch):
+    """With a budget that fits only two of four KV heads a page, the grid
+    takes two head blocks a slot: still the reference, and bit-exact
+    against the dense kernel over the view with the same blocks."""
+    import importlib
+    fd = importlib.import_module("repro.kernels.flash_decode")
+    hkv, g, d = 4, 2, 128
+    monkeypatch.setattr(fd, "KV_BLOCK_BUDGET", 4 * 8 * 2 * d * 4)
+    assert heads_per_block(hkv, d, 8, 4) == 2
+    for call in (fd._paged_flash_call, fd._flash_decode_call):
+        call.clear_cache()
+    q, kp, vp, table, pos_map, pos = _paged_slots_case(
+        np.random.RandomState(14), hkv, g, d)
+    out = np.asarray(paged_flash_decode(q, kp, vp, table, pos_map, pos))
+    shape = (q.shape[0], pos_map.shape[1], hkv, d)
+    k_view = paged_view(kp, table, pos_map).reshape(shape)
+    v_view = paged_view(vp, table, pos_map).reshape(shape)
+    view = np.asarray(flash_decode(q, k_view, v_view, pos_map, pos,
+                                   kv_split=table.shape[1]))
+    for call in (fd._paged_flash_call, fd._flash_decode_call):
+        call.clear_cache()
+    assert np.array_equal(out, view)
+    ref = np.asarray(decode_attention(q, k_view, v_view, pos_map, pos))
+    active = np.asarray(pos) >= 0
+    np.testing.assert_allclose(out[active], ref[active], atol=1e-5)
+    assert np.all(out[~active] == 0.0)
+
+
+def test_heads_per_block_fills_the_budget():
+    """A grid step takes every KV head its page's double-buffered K and V
+    blocks fit in the VMEM budget, in lane-aligned slices."""
+    bf16 = 2
+    assert heads_per_block(32, 128, 128, bf16) == 32    # DeepSeek-LLM-7B
+    assert heads_per_block(8, 128, 128, bf16) == 8      # internlm2, pixtral
+    assert heads_per_block(4, 256, 16, bf16) == 4       # gemma3 global
+    hb = heads_per_block(64, 256, 128, bf16)            # 32 MiB whole
+    assert hb < 64 and 64 % hb == 0 and hb == 32
+    assert 4 * 128 * hb * 256 * bf16 <= 8 << 20
+    # a slice of heads must be lane-aligned, else the block is the row
+    assert heads_per_block(4, 32, 8, 4) == 4
+    assert heads_per_block(2, 16, 1 << 20, 4) == 2      # nothing fits
 
 
 def test_default_kv_split_and_override():

@@ -4,8 +4,9 @@ Interpret mode (the CPU oracle of tests/test_pallas_serving.py) accepts
 block shapes the chip's Mosaic compiler refuses, so each kernel of the
 serving path is compiled here for a *described* v5e chip -- no chip
 attached: the plan-consuming BSR matmul at BERT-base fused-QKV widths, its
-int8 variant with both scale granularities, and flash / paged flash decode
-at gemma3-4b attention widths. A passing compile is not a chip run; it only
+int8 variant with both scale granularities, flash decode at gemma3-4b
+attention widths and paged flash decode at every width a benchmark cell or
+a registered GQA config serves. A passing compile is not a chip run; it only
 proves the compiler takes the kernel. The compiled text also pins the
 kernels' own names, which the benchmark's rooflines look for in the trace.
 
@@ -27,7 +28,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.bsr_matmul import pack_bsr, plan_dds, plan_dds_q
 from repro.kernels.exec_plan import (build_plan, pack_plan_data,
                                      plan_kernel_sequence, quant_granularity)
-from repro.kernels.flash_decode import flash_decode, paged_flash_decode
+from repro.kernels.flash_decode import (flash_decode, heads_per_block,
+                                        paged_flash_decode)
 from repro.kernels.moe_gmm import _moe_gmm_call, _ffn_tile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -118,10 +120,16 @@ def test_flash_decode_compiles(one_chip, hkv, g, d):
         s((b, t), jnp.int32), s((b,), jnp.int32))
 
 
-#: paged decode widths: the benchmark's DeepSeek-LLM-7B (32 MHA KV heads
-#: of 128, pages of 128, 16 slots x 12 pages, 8 stacked layers) and
-#: gemma3-4b's (4 KV heads x 2 of 256, pages of 16)
+#: paged decode widths (slots, KV heads, group, head_dim, page size,
+#: pages a table, stacked layers): the benchmark's DeepSeek-LLM-7B (32 MHA
+#: KV heads of 128, pages of 128) in its chat cell (16 slots x 12 pages)
+#: and its long-context cell (12 slots x 32 pages), a GQA shape as
+#: internlm2 and pixtral serve it (8 KV heads x 4 of 128), and gemma3-4b's
+#: (4 KV heads x 2 of 256, pages of 16); every one a single head block a
+#: page (heads_per_block)
 PAGED_WIDTHS = {"deepseek_7b": (16, 32, 1, 128, 128, 12, 8),
+                "deepseek_7b_longctx": (12, 32, 1, 128, 128, 32, 8),
+                "gqa_8x4": (16, 8, 4, 128, 128, 12, 8),
                 "gemma3_4b": (4, 4, 2, 256, 16, 64, 2)}
 
 
@@ -141,6 +149,8 @@ def _paged_flash_decode(one_chip, widths="deepseek_7b"):
 
 def test_paged_flash_decode_compiles(one_chip):
     for widths in PAGED_WIDTHS:
+        b, hkv, g, d, ps, npg, n_layers = PAGED_WIDTHS[widths]
+        assert heads_per_block(hkv, d, ps, 2) == hkv, widths
         _paged_flash_decode(one_chip, widths)
 
 

@@ -139,6 +139,41 @@ def test_engine_spans_under_a_session(servable, tmp_path, layout, sched):
     assert host == collections.Counter({k: len(v) for k, v in by.items()})
 
 
+def test_window_span_counts_live_and_walked_kv_pages(servable, tmp_path):
+    """A paged engine's windows carry how many KV pages its decoding slots
+    hold up to their positions, against the pages their tables span: two
+    slots at positions 5 and 20 (pages of 16, tables of 64 / 16 = 4
+    pages) decode a window of 4 steps, then one of 1 at 9 and 24."""
+    eng = servable.engine(max_slots=2, cache_len=64, sync_every=4,
+                          kv_layout="paged")
+    assert (eng.kv_page_size, eng._table_width) == (16, 4)
+    _serve(eng, _prompts(3))            # compiles outside the session
+    st0 = (eng.stats.kv_pages_live, eng.stats.kv_pages_walked)
+    rng = np.random.RandomState(3)
+    with jax.profiler.trace(str(tmp_path)):
+        _serve(eng, [rng.randint(0, 256, (n,)).tolist() for n in (5, 20)])
+    wins = [sp.args for sp in trace.recorded()
+            if sp.name == "repro.engine.window"]
+    assert all(a["kv_pages_live"] <= a["kv_pages_walked"] for a in wins)
+    # positions 5, 20 -> pages 1 + 2; then 9, 24 -> 1 + 2
+    assert [(a["k"], a["kv_pages_live"], a["kv_pages_walked"])
+            for a in wins] == [(4, 4 * (1 + 2), 4 * 2 * 4),
+                               (1, 1 * (1 + 2), 1 * 2 * 4)]
+    assert (eng.stats.kv_pages_live - st0[0],
+            eng.stats.kv_pages_walked - st0[1]) == (15, 40)
+
+
+def test_dense_window_span_has_no_kv_pages(servable, tmp_path):
+    eng = servable.engine(max_slots=2, cache_len=64, sync_every=4)
+    _serve(eng, _prompts(2))
+    with jax.profiler.trace(str(tmp_path)):
+        _serve(eng, _prompts(2))
+    wins = [sp.args for sp in trace.recorded()
+            if sp.name == "repro.engine.window"]
+    assert wins and not any("kv_pages_live" in a for a in wins)
+    assert eng.stats.kv_pages_live == eng.stats.kv_pages_walked == 0
+
+
 def test_servable_forward_span_is_dispatch_only(servable, tmp_path):
     toks = np.arange(16, dtype=np.int32)[None]
     jax.block_until_ready(servable.forward(toks))
